@@ -22,10 +22,9 @@
                      ratio + informational end-to-end engine ratio)
   fused_superstep  — PR 10 fused window front-end: the one-jit fused select +
                      gather + conflict + group + release-rank program vs the
-                     same stages dispatched separately (gated); on TPU also
-                     the compiled Pallas megakernel vs the stitched twin
-                     ("requires": "tpu"); asserts fused engine == stitched
-                     engine == heapq oracle before timing
+                     same stages dispatched separately (gated); asserts
+                     fused engine == stitched engine == heapq oracle before
+                     timing
   adaptive_exec    — PR 5 monitoring-driven exec width: ladder policy vs the
                      static exec_cap=256 default on spill-heavy windows
                      (fewer windows, same events, oracle-exact)
@@ -491,12 +490,6 @@ def bench_fused_superstep(pool_cap=4096, exec_cap=256, iters=500):
     would only dilute the seam the gate pins. Byte-identity of the two
     tails (and of the ref oracle ``fused_select_ref``) is asserted in-bench.
 
-    On a TPU backend the same family adds the compiled-Pallas lane
-    (``fused_superstep_tpu_*``, ``"requires": "tpu"`` in baseline.json): the
-    complete megakernel — sort included, ring cursor in SMEM, every
-    intermediate VMEM-resident — against the one-jit stitched twin
-    ``engine.fused_select_xla``, both compiled.
-
     Before timing anything the row asserts end-to-end byte-identity: the
     fused engine (``spec.fused_select=True``, the interpret-Pallas path off
     TPU) runs the identical trace/counters/world as the stitched engine and
@@ -505,8 +498,7 @@ def bench_fused_superstep(pool_cap=4096, exec_cap=256, iters=500):
     interpreted megakernel *loses*; the gate pins the fusion seam itself).
     """
     from repro.core import merged_engine_trace, run_sequential, sync
-    from repro.core.engine import (fused_select_xla, group_by_kind_xla,
-                                   select_events_xla)
+    from repro.core.engine import group_by_kind_xla, select_events_xla
     from repro.kernels import ref as kref
 
     # --- byte-identity proof: fused engine == stitched engine == oracle ---
@@ -627,39 +619,6 @@ def bench_fused_superstep(pool_cap=4096, exec_cap=256, iters=500):
          f"engine_events_s_fused={erates['fused']:.0f};"
          f"engine_events_s_stitched={erates['stitched']:.0f};"
          f"engine_speedup={erates['fused'] / erates['stitched']:.2f}x")
-
-    if jax.default_backend() == "tpu":
-        # the compiled megakernel itself (sort included, SMEM ring cursor)
-        # vs the one-jit stitched twin
-        from repro.kernels import ops
-
-        @jax.jit
-        def one_jit_stitched(tail):
-            fs = fused_select_xla(tk, sq, safe, tm, kind, src, dst, ctx,
-                                  pay, valid, tbl, res, tail, m, **kw)
-            return fs.exec_safe, fs.clean, fs.order, fs.rel_pos
-
-        def pallas(tail):
-            fs = ops.fused_select(tk, sq, safe, tm, kind, src, dst, ctx, pay,
-                                  valid, tbl, res, tail, m, **kw)
-            return fs.exec_safe, fs.clean, fs.order, fs.rel_pos
-
-        prates = {}
-        for label, fn in (("pallas", pallas), ("stitched", one_jit_stitched)):
-            jax.block_until_ready(fn(tail))        # compile
-            t0 = time.perf_counter()
-            for _ in range(iters):
-                out = fn(tail)
-            jax.block_until_ready(out)
-            prates[label] = iters / (time.perf_counter() - t0)
-        for a, b in zip(pallas(tail), one_jit_stitched(tail)):
-            assert (np.asarray(a) == np.asarray(b)).all()
-        emit(f"fused_superstep_tpu_p{pool_cap}",
-             1e6 / prates["pallas"],
-             f"windows_s_pallas={prates['pallas']:.0f};"
-             f"windows_s_stitched={prates['stitched']:.0f};"
-             f"exec_cap={m};"
-             f"speedup={prates['pallas'] / prates['stitched']:.2f}x")
 
 
 def bench_adaptive_exec(width=1024, n_ticks=4, lookahead=4, pool_cap=4096):
@@ -888,6 +847,13 @@ def bench_shard_scaling(n_agents=64, n_ticks=32, lookahead=2):
     import subprocess
     import sys
 
+    if jax.default_backend() != "cpu":
+        # the children would contend with this process for the accelerator
+        # (one process per chip); forced host devices exist only on CPU
+        print(f"# shard_scaling skipped: backend {jax.default_backend()} — "
+              "its forced-host-device children need the CPU backend")
+        return
+
     child = r"""
 import os, sys
 os.environ["XLA_FLAGS"] = ("--xla_force_host_platform_device_count="
@@ -1102,6 +1068,11 @@ def main() -> None:
                          "preempt+resume wall vs uninterrupted; run by the "
                          "distributed CI job)")
     args = ap.parse_args()
+    from repro.launch import compile_cache
+    compile_cache.enable()
+    dev = jax.devices()
+    print(f"# device: platform={dev[0].platform} kind={dev[0].device_kind} "
+          f"count={len(dev)}")
     print("name,us_per_call,derived")
     if args.quick:
         bench_exec_compaction(pool_caps=(4096,))

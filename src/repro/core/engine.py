@@ -75,6 +75,7 @@ stays in lockstep on one jit-cached window program.
 from __future__ import annotations
 
 import functools
+import os
 from typing import Callable, NamedTuple
 
 import jax
@@ -96,14 +97,11 @@ from repro.core.registry import registry_of
 from repro.kernels.event_select import FusedSelect
 
 AXIS = "agents"
+# run_distributed over several TPU chips is refused unless this is set: its
+# equality with run_local across chips is not yet shown on the chip
+MULTICHIP_TPU_ENV = "REPRO_MULTICHIP_TPU"
 
-# jax >= 0.6 exposes shard_map at top level with check_vma; older releases keep
-# it in jax.experimental with the check_rep spelling.
-if hasattr(jax, "shard_map"):
-    _shard_map = functools.partial(jax.shard_map, check_vma=False)
-else:  # pragma: no cover - exercised only on older jax
-    from jax.experimental.shard_map import shard_map as _sm
-    _shard_map = functools.partial(_sm, check_rep=False)
+_shard_map = functools.partial(jax.shard_map, check_vma=False)
 
 
 class ShardAxes(NamedTuple):
@@ -831,6 +829,10 @@ class Engine:
             a2a = functools.partial(jax.lax.all_to_all, axis_name=axis,
                                     split_axis=0, concat_axis=0)
 
+        # the payload's int32 fields ride as f32 bit patterns, and small ints
+        # are f32 denormals, which a cross-chip TPU all_to_all flushes to
+        # zero: exchange the raw bits as int32 and view them back
+        payload_bits = jax.lax.bitcast_convert_type(emits.payload, jnp.int32)
         rx = ev.EventBatch(
             time=a2a(scatter(emits.time, ev.T_INF)).reshape(A * rcap),
             seq=a2a(scatter(emits.seq, 0)).reshape(A * rcap),
@@ -838,8 +840,9 @@ class Engine:
             src=a2a(scatter(emits.src, 0)).reshape(A * rcap),
             dst=a2a(scatter(emits.dst, 0)).reshape(A * rcap),
             ctx=a2a(scatter(emits.ctx, 0)).reshape(A * rcap),
-            payload=a2a(scatter(emits.payload, 0.0)).reshape(A * rcap,
-                                                             ev.PAYLOAD),
+            payload=jax.lax.bitcast_convert_type(
+                a2a(scatter(payload_bits, 0)), jnp.float32).reshape(
+                    A * rcap, ev.PAYLOAD),
             valid=a2a(scatter(emits.valid, False)).reshape(A * rcap),
         )
         if migrate:
@@ -1029,6 +1032,12 @@ class Engine:
                 f"run_distributed needs a 1-D mesh, got axes {mesh.axis_names}")
         shard = mesh.axis_names[0]
         d = int(mesh.devices.size)
+        if (d > 1 and mesh.devices.flat[0].platform == "tpu"
+                and os.environ.get(MULTICHIP_TPU_ENV) != "1"):
+            raise RuntimeError(
+                f"run_distributed over {d} TPU chips is not yet shown to equal "
+                f"run_local; set {MULTICHIP_TPU_ENV}=1 to run it anyway "
+                "(chip_smoke.py --four-chips does, and compares the two)")
         k = -(-self.spec.n_agents // d)
         lane = "lanes" if shard != "lanes" else "lanes2"
         return ShardAxes(shard=shard, lane=lane, n_shards=d, n_lanes=k)

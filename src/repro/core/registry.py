@@ -675,6 +675,12 @@ class Registry:
             if x.dtype == jnp.bool_:
                 y = jax.lax.psum(jnp.where(m, x.astype(jnp.int32), 0), axis)
                 return y > 0
+            if x.dtype == jnp.float32:
+                # sum the raw bits: the owner's value arrives exactly, -0.0
+                # and denormals included, whatever the float reduction does
+                bits = jax.lax.bitcast_convert_type(x, jnp.int32)
+                y = jax.lax.psum(jnp.where(m, bits, 0), axis)
+                return jax.lax.bitcast_convert_type(y, jnp.float32)
             return jax.lax.psum(jnp.where(m, x, jnp.zeros((), x.dtype)), axis)
 
         lp_mine = world.lp_agent == me

@@ -1,12 +1,18 @@
-"""Event-selection Pallas kernel: the DES engine's per-window (time, seq) sort.
+"""Event-selection Pallas kernels: the DES engine's window front end.
 
 The conservative window's hot loop starts by ordering the event pool by
 (timestamp, tie-break seq) with unsafe slots pushed to the back (their key is
-T_INF). This kernel runs a bitonic sorting network entirely in VMEM over the
+T_INF). These kernels run a bitonic sorting network entirely in VMEM over the
 (time, seq, index) triple — log^2(N) vectorized compare-exchange stages, no HBM
-traffic beyond one read and one write of the pool keys. The XOR-partner exchange
-of the classic network is expressed as a (N/2j, 2, j) reshape + pair swap, which
-vectorizes on the VPU.
+traffic beyond one read and one write of the pool keys.
+
+Layout: every vector is padded to a power of two of at least one lane row and
+held as an ``(N / 128, 128)`` int32 tile, flat position ``row * 128 + lane``.
+The compare-exchange partner of flat slot ``p`` is ``p ^ j``: ``j`` lanes away
+for ``j < 128`` and ``j / 128`` rows away otherwise, so each stage is two
+``pltpu.roll`` rotations along one axis plus selects — no reshapes, no
+unaligned slices, which is what Mosaic compiles. Prefix sums are log-step
+shift-adds built from the same rotations (lanes first, then row offsets).
 
 ``sort_events`` outputs the full permutation (i32 indices), matching
 engine.lexsort_time_seq exactly (stable for equal (time, seq) pairs because the
@@ -38,71 +44,122 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 I32_MAX = jnp.int32(2**31 - 1)
+LANES = 128
 
 
-def _lex_less(t1, s1, i1, t2, s2, i2):
-    return ((t1 < t2)
-            | ((t1 == t2) & (s1 < s2))
-            | ((t1 == t2) & (s1 == s2) & (i1 < i2)))
+def _pow2_tile(n: int) -> int:
+    """Flat width of a sort tile: the power of two >= n, at least one row."""
+    return max(1 << max((n - 1).bit_length(), 0), LANES)
 
 
-def _sort_kernel(time_ref, seq_ref, perm_ref, *, n: int):
-    t = time_ref[0]                        # (n,)
-    s = seq_ref[0]
-    idx = jax.lax.broadcasted_iota(jnp.int32, (1, n), 1)[0]
+def _row_tile(n: int) -> int:
+    """Flat width of a scan tile: n rounded up to whole lane rows."""
+    return max(-(-n // LANES), 1) * LANES
 
+
+def _to_tile(x: jax.Array, n: int, fill) -> jax.Array:
+    """(k,) -> (n // 128, 128) int32, padded with ``fill`` beyond k."""
+    return jnp.full((n,), fill, jnp.int32).at[: x.shape[0]].set(
+        x.astype(jnp.int32)).reshape(n // LANES, LANES)
+
+
+def _flat_pos(shape) -> jax.Array:
+    return (jax.lax.broadcasted_iota(jnp.int32, shape, 0) * shape[1]
+            + jax.lax.broadcasted_iota(jnp.int32, shape, 1))
+
+
+def _roll(x, shift: int, axis: int):
+    shift %= x.shape[axis]
+    return x if shift == 0 else pltpu.roll(x, shift, axis)
+
+
+def _lex_less(a, b):
+    """Elementwise lexicographic a < b over parallel key lists."""
+    less = a[-1] < b[-1]
+    for x, y in zip(reversed(a[:-1]), reversed(b[:-1])):
+        less = (x < y) | ((x == y) & less)
+    return less
+
+
+def _bitonic(keys, carry=()):
+    """Sort (R, 128) tiles ascending by the lexicographic ``keys`` (the last
+    key must be distinct per slot); the ``carry`` tiles ride along."""
+    keys, carry = list(keys), list(carry)
+    shape = keys[0].shape
+    n = shape[0] * shape[1]
+    pos = _flat_pos(shape)
     k = 2
     while k <= n:
         j = k // 2
         while j >= 1:
-            def pairs(x):
-                return x.reshape(n // (2 * j), 2, j)
+            axis, d = (1, j) if j < shape[1] else (0, j // shape[1])
+            size = shape[axis]
+            # the partner p ^ j sits d slots ahead or behind along ``axis``; the
+            # rotated position grid says which rotation delivers it
+            ahead = _roll(pos, size - d, axis) == (pos ^ j)
 
-            tp, sp, ip = pairs(t), pairs(s), pairs(idx)
-            lo_i = jax.lax.broadcasted_iota(jnp.int32, (n // (2 * j), 1, j), 0)
-            lo_r = jax.lax.broadcasted_iota(jnp.int32, (n // (2 * j), 1, j), 2)
-            lo_index = lo_i * (2 * j) + lo_r                  # global index of lo
-            ascend = (lo_index & k) == 0                      # (g, 1, j)
+            def partner(x, ahead=ahead, axis=axis, d=d, size=size):
+                return jnp.where(ahead, _roll(x, size - d, axis),
+                                 _roll(x, d, axis))
 
-            t_lo, t_hi = tp[:, :1], tp[:, 1:]
-            s_lo, s_hi = sp[:, :1], sp[:, 1:]
-            i_lo, i_hi = ip[:, :1], ip[:, 1:]
-            le = _lex_less(t_lo, s_lo, i_lo, t_hi, s_hi, i_hi)
-            swap = jnp.where(ascend, ~le, le)
-
-            def mix(lo, hi):
-                nlo = jnp.where(swap, hi, lo)
-                nhi = jnp.where(swap, lo, hi)
-                return jnp.concatenate([nlo, nhi], axis=1).reshape(n)
-
-            t, s, idx = mix(t_lo, t_hi), mix(s_lo, s_hi), mix(i_lo, i_hi)
+            pk = [partner(x) for x in keys]
+            keep_min = ((pos & j) == 0) == ((pos & k) == 0)
+            take = _lex_less(pk, keys) == keep_min
+            keys = [jnp.where(take, p, x) for p, x in zip(pk, keys)]
+            carry = [jnp.where(take, partner(x), x) for x in carry]
             j //= 2
         k *= 2
+    return keys, carry
 
+
+def _behind(x, s: int, axis: int):
+    """y[c] = x[c - s] along ``axis``, zero for c < s."""
+    size = x.shape[axis]
+    idx = jax.lax.broadcasted_iota(jnp.int32, x.shape, axis)
+    from_a = _roll(idx, s, axis) == idx - s
+    from_b = _roll(idx, size - s, axis) == idx - s
+    return jnp.where(from_a, _roll(x, s, axis),
+                     jnp.where(from_b, _roll(x, size - s, axis), 0))
+
+
+def _exclusive_cumsum(w):
+    """Row-major exclusive prefix sum of an int32 (R, 128) tile: log-step
+    shift-adds along the lanes, then the same over the row totals."""
+    rows, lanes = w.shape
+    x = w
+    s = 1
+    while s < lanes:
+        x = x + _behind(x, s, 1)
+        s *= 2
+    tot = jnp.broadcast_to(jnp.sum(w, axis=1, keepdims=True), w.shape)
+    off = tot
+    s = 1
+    while s < rows:
+        off = off + _behind(off, s, 0)
+        s *= 2
+    return x - w + off - tot
+
+
+def _sort_kernel(time_ref, seq_ref, perm_ref):
+    t = time_ref[...]
+    (_, _, idx), _ = _bitonic([t, seq_ref[...], _flat_pos(t.shape)])
     # the out block may be a prefix of the sorted permutation (select_events)
-    perm_ref[0] = idx[: perm_ref.shape[1]]
+    perm_ref[...] = idx[: perm_ref.shape[0]]
 
 
 def _run_sort(time_key: jax.Array, seq: jax.Array, m: int, *, interpret):
     """Shared pallas_call: sort padded keys, emit the first ``m`` indices."""
-    cap = time_key.shape[0]
-    n = 1 << max((cap - 1).bit_length(), 1)
-    mpad = 1 << max((m - 1).bit_length(), 1)
-    tpad = jnp.full((n,), I32_MAX, jnp.int32).at[:cap].set(time_key)[None]
-    spad = jnp.full((n,), I32_MAX, jnp.int32).at[:cap].set(seq)[None]
-    kernel = functools.partial(_sort_kernel, n=n)
+    n = _pow2_tile(time_key.shape[0])
     perm = pl.pallas_call(
-        kernel,
-        grid=(1,),
-        in_specs=[pl.BlockSpec((1, n), lambda i: (0, 0)),
-                  pl.BlockSpec((1, n), lambda i: (0, 0))],
-        out_specs=pl.BlockSpec((1, mpad), lambda i: (0, 0)),
-        out_shape=jax.ShapeDtypeStruct((1, mpad), jnp.int32),
+        _sort_kernel,
+        out_shape=jax.ShapeDtypeStruct((_pow2_tile(m) // LANES, LANES),
+                                       jnp.int32),
         interpret=interpret,
-    )(tpad, spad)
-    return perm[0, :m]
+    )(_to_tile(time_key, n, I32_MAX), _to_tile(seq, n, I32_MAX))
+    return perm.reshape(-1)[:m]
 
 
 def sort_events(time_key: jax.Array, seq: jax.Array, *, interpret=False):
@@ -122,8 +179,13 @@ def select_events(time_key: jax.Array, seq: jax.Array, exec_cap: int, *,
                      interpret=interpret)
 
 
+def _total(x):
+    """Sum of a whole tile as a (1, 1) array."""
+    return jnp.sum(jnp.sum(x, axis=1, keepdims=True), axis=0, keepdims=True)
+
+
 def _group_kernel(kind_ref, act_ref, order_ref, rank_ref, counts_ref, *,
-                  n: int, n_kinds: int):
+                  n_kinds: int):
     """Segment-rank grouping: bitonic sort by (kind, index) + in-VMEM ranks.
 
     Active rows get key = kind, inactive rows key = n_kinds (grouping them
@@ -133,84 +195,47 @@ def _group_kernel(kind_ref, act_ref, order_ref, rank_ref, counts_ref, *,
     the n_kinds+1 possible keys (position minus the segment's exclusive
     prefix count), so no dynamic gather is needed on the VPU.
     """
-    kd = kind_ref[0]                       # (n,)
-    act = act_ref[0] != 0
-    idx = jax.lax.broadcasted_iota(jnp.int32, (1, n), 1)[0]
-    key = jnp.where(act, jnp.clip(kd, 0, n_kinds - 1), jnp.int32(n_kinds))
-
-    k = 2
-    while k <= n:
-        j = k // 2
-        while j >= 1:
-            def pairs(x):
-                return x.reshape(n // (2 * j), 2, j)
-
-            kp, ip = pairs(key), pairs(idx)
-            lo_i = jax.lax.broadcasted_iota(jnp.int32, (n // (2 * j), 1, j), 0)
-            lo_r = jax.lax.broadcasted_iota(jnp.int32, (n // (2 * j), 1, j), 2)
-            lo_index = lo_i * (2 * j) + lo_r
-            ascend = (lo_index & k) == 0
-
-            k_lo, k_hi = kp[:, :1], kp[:, 1:]
-            i_lo, i_hi = ip[:, :1], ip[:, 1:]
-            le = (k_lo < k_hi) | ((k_lo == k_hi) & (i_lo < i_hi))
-            swap = jnp.where(ascend, ~le, le)
-
-            def mix(lo, hi):
-                nlo = jnp.where(swap, hi, lo)
-                nhi = jnp.where(swap, lo, hi)
-                return jnp.concatenate([nlo, nhi], axis=1).reshape(n)
-
-            key, idx = mix(k_lo, k_hi), mix(i_lo, i_hi)
-            j //= 2
-        k *= 2
-
-    pos = jax.lax.broadcasted_iota(jnp.int32, (1, n), 1)[0]
+    pos = _flat_pos(kind_ref.shape)
+    key = jnp.where(act_ref[...] != 0,
+                    jnp.clip(kind_ref[...], 0, n_kinds - 1), n_kinds)
+    (key, idx), _ = _bitonic([key, pos])
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, LANES), 1)
     rank = pos
-    total = jnp.int32(0)
-    counts = []
+    total = jnp.zeros((1, 1), jnp.int32)
+    counts = jnp.zeros((1, LANES), jnp.int32)
     for g in range(n_kinds + 1):
         in_g = key == g
-        cnt = jnp.sum(in_g.astype(jnp.int32))
+        cnt = _total(in_g.astype(jnp.int32))
         rank = rank - jnp.where(in_g, total, 0)
         if g < n_kinds:
-            counts.append(cnt)
+            counts = jnp.where(lane == g, cnt, counts)
         total = total + cnt
+    order_ref[...] = idx
+    rank_ref[...] = rank
+    counts_ref[...] = counts
 
-    order_ref[0] = idx
-    rank_ref[0] = rank
-    counts_ref[0] = jnp.stack(counts)
 
-
-def _ring_slots_kernel(ring_ref, want_ref, head_ref, out_ref, *,
-                       n: int, cap: int, chunk: int):
+def _ring_slots_kernel(ring_ref, want_ref, head_ref, out_ref, *, cap: int):
     """Free-ring slot assignment: prefix-sum the insert mask, gather the ring.
 
     The insert path of the free-ring event pool (``events.insert``): the r-th
     masked batch row takes the slot at ring position ``(head + r) % cap``.
-    The insert rank is a log-step shift-add prefix sum over the batch lane;
-    the ring gather is expressed as chunked one-hot selection (iota-compare +
-    masked sum) so no dynamic VMEM gather is needed on the VPU — the same
-    trick the segment-rank kernel uses for its rank counts.
+    The insert rank is the log-step exclusive prefix sum; the ring gather is
+    a blocked one-hot selection — 128 ring slots down the sublanes against
+    128 batch rows across the lanes, masked and summed over the sublanes — so
+    no dynamic VMEM gather is needed on the VPU.
     """
-    want = want_ref[0]                     # (n,) int32 0/1
-    head = head_ref[0][0]
-    x = want
-    s = 1
-    while s < n:
-        x = x + jnp.concatenate([jnp.zeros((s,), jnp.int32), x[:-s]])
-        s *= 2
-    rank = x - want                        # exclusive prefix = insert rank
-    pos = (head + rank) % jnp.int32(cap)
-
-    acc = jnp.zeros((n,), jnp.int32)
-    ids0 = jax.lax.broadcasted_iota(jnp.int32, (n, chunk), 1)
-    for c in range(0, cap, chunk):
-        ids = ids0 + jnp.int32(c)
-        seg = ring_ref[0, c:c + chunk]     # (chunk,) static slice
-        eq = pos[:, None] == ids
-        acc = acc + jnp.sum(jnp.where(eq, seg[None, :], 0), axis=1)
-    out_ref[0] = acc
+    pos = (head_ref[...] + _exclusive_cumsum(want_ref[...])) % jnp.int32(cap)
+    ring = ring_ref[...]
+    ids = jax.lax.broadcasted_iota(jnp.int32, (LANES, 1), 0)
+    for rb in range(out_ref.shape[0]):
+        prow = pos[rb:rb + 1]
+        acc = jnp.zeros((1, LANES), jnp.int32)
+        for rr in range(ring.shape[0]):
+            col = ring[rr:rr + 1].reshape(LANES, 1)
+            hit = (ids + jnp.int32(rr * LANES)) == prow
+            acc = acc + jnp.sum(jnp.where(hit, col, 0), axis=0, keepdims=True)
+        out_ref[rb:rb + 1] = acc
 
 
 def ring_slots(free_ring: jax.Array, head: jax.Array, want: jax.Array, *,
@@ -225,39 +250,22 @@ def ring_slots(free_ring: jax.Array, head: jax.Array, want: jax.Array, *,
     """
     cap = free_ring.shape[0]
     nb = want.shape[0]
-    n = 1 << max((nb - 1).bit_length(), 1)
-    chunk = min(cap, 512)
-    capp = ((cap + chunk - 1) // chunk) * chunk
-    ringp = jnp.zeros((capp,), jnp.int32).at[:cap].set(free_ring)[None]
-    wantp = jnp.zeros((n,), jnp.int32).at[:nb].set(
-        want.astype(jnp.int32))[None]
-    headp = jnp.asarray(head, jnp.int32).reshape(1, 1)
-    kernel = functools.partial(_ring_slots_kernel, n=n, cap=cap, chunk=chunk)
+    nw = _row_tile(nb)
     out = pl.pallas_call(
-        kernel,
-        grid=(1,),
-        in_specs=[pl.BlockSpec((1, capp), lambda i: (0, 0)),
-                  pl.BlockSpec((1, n), lambda i: (0, 0)),
-                  pl.BlockSpec((1, 1), lambda i: (0, 0))],
-        out_specs=pl.BlockSpec((1, n), lambda i: (0, 0)),
-        out_shape=jax.ShapeDtypeStruct((1, n), jnp.int32),
+        functools.partial(_ring_slots_kernel, cap=cap),
+        out_shape=jax.ShapeDtypeStruct((nw // LANES, LANES), jnp.int32),
         interpret=interpret,
-    )(ringp, wantp, headp)
-    return out[0, :nb]
+    )(_to_tile(free_ring, _row_tile(cap), 0), _to_tile(want, nw, 0),
+      jnp.broadcast_to(jnp.asarray(head, jnp.int32), (1, LANES)))
+    return out.reshape(-1)[:nb]
 
 
-def _trace_rank_kernel(want_ref, out_ref, *, n: int):
+def _trace_rank_kernel(want_ref, out_ref):
     """Exclusive prefix rank of the processed mask: the r-th masked window
     lane writes absolute trace position ``trace_n + r``. Same log-step
-    shift-add prefix sum as the ring-slot kernel, without the ring gather —
-    the write itself is a plain XLA scatter on the (cap, 4) trace buffer."""
-    want = want_ref[0]                     # (n,) int32 0/1
-    x = want
-    s = 1
-    while s < n:
-        x = x + jnp.concatenate([jnp.zeros((s,), jnp.int32), x[:-s]])
-        s *= 2
-    out_ref[0] = x - want                  # exclusive prefix
+    prefix sum as the ring-slot kernel, without the ring gather — the write
+    itself is a plain XLA scatter on the (cap, 4) trace buffer."""
+    out_ref[...] = _exclusive_cumsum(want_ref[...])
 
 
 def trace_rank(mask: jax.Array, *, interpret=False):
@@ -269,19 +277,13 @@ def trace_rank(mask: jax.Array, *, interpret=False):
     the running count like the XLA cumsum — the append masks them out).
     """
     nb = mask.shape[0]
-    n = 1 << max((nb - 1).bit_length(), 1)
-    wpad = jnp.zeros((n,), jnp.int32).at[:nb].set(
-        mask.astype(jnp.int32))[None]
-    kernel = functools.partial(_trace_rank_kernel, n=n)
+    nw = _row_tile(nb)
     out = pl.pallas_call(
-        kernel,
-        grid=(1,),
-        in_specs=[pl.BlockSpec((1, n), lambda i: (0, 0))],
-        out_specs=pl.BlockSpec((1, n), lambda i: (0, 0)),
-        out_shape=jax.ShapeDtypeStruct((1, n), jnp.int32),
+        _trace_rank_kernel,
+        out_shape=jax.ShapeDtypeStruct((nw // LANES, LANES), jnp.int32),
         interpret=interpret,
-    )(wpad)
-    return out[0, :nb]
+    )(_to_tile(mask, nw, 0))
+    return out.reshape(-1)[:nb]
 
 
 def _route_rank_kernel(dst_ref, rank_ref, *, n: int, chunk: int):
@@ -344,26 +346,20 @@ def group_by_kind(kind: jax.Array, active: jax.Array, n_kinds: int, *,
     ``rank`` gives each grouped row's index within its kind segment; ``counts``
     is the (n_kinds,) active population per kind.
     """
+    if n_kinds > LANES:
+        raise ValueError(f"group_by_kind counts at most {LANES} kinds, "
+                         f"got n_kinds={n_kinds}")
     cap = kind.shape[0]
-    n = 1 << max((cap - 1).bit_length(), 1)
-    kpad = jnp.zeros((n,), jnp.int32).at[:cap].set(kind)[None]
-    apad = jnp.zeros((n,), jnp.int32).at[:cap].set(
-        active.astype(jnp.int32))[None]
-    kernel = functools.partial(_group_kernel, n=n, n_kinds=n_kinds)
+    n = _pow2_tile(cap)
+    tile = jax.ShapeDtypeStruct((n // LANES, LANES), jnp.int32)
     order, rank, counts = pl.pallas_call(
-        kernel,
-        grid=(1,),
-        in_specs=[pl.BlockSpec((1, n), lambda i: (0, 0)),
-                  pl.BlockSpec((1, n), lambda i: (0, 0))],
-        out_specs=[pl.BlockSpec((1, n), lambda i: (0, 0)),
-                   pl.BlockSpec((1, n), lambda i: (0, 0)),
-                   pl.BlockSpec((1, n_kinds), lambda i: (0, 0))],
-        out_shape=[jax.ShapeDtypeStruct((1, n), jnp.int32),
-                   jax.ShapeDtypeStruct((1, n), jnp.int32),
-                   jax.ShapeDtypeStruct((1, n_kinds), jnp.int32)],
+        functools.partial(_group_kernel, n_kinds=n_kinds),
+        out_shape=[tile, tile,
+                   jax.ShapeDtypeStruct((1, LANES), jnp.int32)],
         interpret=interpret,
-    )(kpad, apad)
-    return order[0, :cap], rank[0, :cap], counts[0]
+    )(_to_tile(kind, n, 0), _to_tile(active, n, 0))
+    return (order.reshape(-1)[:cap], rank.reshape(-1)[:cap],
+            counts[0, :n_kinds])
 
 
 class FusedSelect(NamedTuple):
@@ -398,8 +394,8 @@ def _fused_select_kernel(tkey_ref, seq_ref, safe_ref, time_ref, kind_ref,
                          idx_out, safe_out, time_out, seq_out, kind_out,
                          src_out, dst_out, ctx_out, valid_out, pay_out,
                          clean_out, order_out, rel_out, *,
-                         n: int, m: int, mpad: int, cap: int, n_kinds: int,
-                         n_res: int, n_pay: int, chunk: int):
+                         m: int, cap: int, n_kinds: int, n_res: int,
+                         n_pay: int):
     """The superstep megakernel: select + gather + conflict + group + release.
 
     One VMEM-resident pass fuses the four front-end stages XLA otherwise
@@ -410,135 +406,75 @@ def _fused_select_kernel(tkey_ref, seq_ref, safe_ref, time_ref, kind_ref,
        valid, the conflict key columns, and all PAYLOAD payload lanes) rides
        through the compare-exchange as sort payload, so the window's slot
        *gather* falls out of the sort for free: after the network, lane i of
-       every carried array IS pool slot ``exec_idx[i]``'s field. No dynamic
+       every carried tile IS pool slot ``exec_idx[i]``'s field. No dynamic
        VMEM gather, no HBM round-trip for the index array.
     2. **Conflict mask**: duplicate detection on the declared component rows
-       (``rkey = table_id * n_res + res``) via a chunked pairwise count —
+       (``rkey = table_id * n_res + res``) via a blocked pairwise count —
        ``cnt[j] = sum_i comp[i] & (rkey[i] == rkey[j])`` — matching
        ``sync.conflict_mask`` semantics exactly (rows with table_id == 0
        never conflict).
     3. **Group-by-kind**: the segment bitonic of ``_group_kernel`` over the
        window lanes, keyed (clean ? kind : n_kinds, position).
-    4. **Release ranks**: the log-step shift-add exclusive prefix sum of the
-       safe mask; with the ``free_tail`` ring cursor resident in SMEM (a
-       scalar block on TPU), each executed slot's reclaim position
-       ``(free_tail + rank) % cap`` leaves the kernel ready for the O(1)
-       ``events.release`` scatter.
+    4. **Release ranks**: the log-step exclusive prefix sum of the safe
+       mask; with the ``free_tail`` ring cursor broadcast across one lane
+       row, each executed slot's reclaim position ``(free_tail + rank) %
+       cap`` leaves the kernel ready for the O(1) ``events.release`` scatter.
     """
-    t = tkey_ref[0]
-    s = seq_ref[0]
-    idx = jax.lax.broadcasted_iota(jnp.int32, (1, n), 1)[0]
-    # every event field rides the sorting network as payload (step 1)
-    carry = [safe_ref[0], time_ref[0], kind_ref[0], src_ref[0], dst_ref[0],
-             ctx_ref[0], valid_ref[0], tbl_ref[0], res_ref[0]]
-    carry += [pay_ref[p] for p in range(n_pay)]
+    t = tkey_ref[...]
+    carry = [safe_ref[...], time_ref[...], kind_ref[...], src_ref[...],
+             dst_ref[...], ctx_ref[...], valid_ref[...], tbl_ref[...],
+             res_ref[...]] + [pay_ref[p] for p in range(n_pay)]
+    (_, s, idx), carry = _bitonic([t, seq_ref[...], _flat_pos(t.shape)],
+                                  carry)
 
-    k = 2
-    while k <= n:
-        j = k // 2
-        while j >= 1:
-            def pairs(x):
-                return x.reshape(n // (2 * j), 2, j)
+    # window prefix: the first ``wrows`` rows hold the m window lanes (lanes
+    # at or beyond m are padding and masked everywhere below)
+    wrows = idx_out.shape[0]
+    carry = [x[:wrows] for x in carry]
+    pos = _flat_pos(idx_out.shape)
+    es = (carry[0] != 0) & (pos < m)
+    kind_w = carry[2]
 
-            tp, sp, ip = pairs(t), pairs(s), pairs(idx)
-            lo_i = jax.lax.broadcasted_iota(jnp.int32, (n // (2 * j), 1, j), 0)
-            lo_r = jax.lax.broadcasted_iota(jnp.int32, (n // (2 * j), 1, j), 2)
-            lo_index = lo_i * (2 * j) + lo_r
-            ascend = (lo_index & k) == 0
-
-            le = _lex_less(tp[:, :1], sp[:, :1], ip[:, :1],
-                           tp[:, 1:], sp[:, 1:], ip[:, 1:])
-            swap = jnp.where(ascend, ~le, le)
-
-            def mix(x):
-                xp = pairs(x)
-                lo, hi = xp[:, :1], xp[:, 1:]
-                return jnp.concatenate([jnp.where(swap, hi, lo),
-                                        jnp.where(swap, lo, hi)],
-                                       axis=1).reshape(n)
-
-            t, s, idx = mix(t), mix(s), mix(idx)
-            carry = [mix(x) for x in carry]
-            j //= 2
-        k *= 2
-
-    # window prefix: only the first m lanes are the window (mpad is the
-    # pow2-padded out width; lanes in [m, mpad) are masked everywhere below)
-    pos = jax.lax.broadcasted_iota(jnp.int32, (1, mpad), 1)[0]
-    sel = pos < m
-    safe_w = carry[0][:mpad]
-    time_w = carry[1][:mpad]
-    kind_w = carry[2][:mpad]
-    es = (safe_w != 0) & sel
-
-    # step 2: conflict mask on the declared (component table, resource row)
-    tb = carry[7][:mpad]
-    rs = carry[8][:mpad]
+    # step 2: conflict mask on the declared (component table, resource row):
+    # 128 lanes i down the sublanes against 128 lanes j across, per block
+    tb, rs = carry[7], carry[8]
     rkey = tb * jnp.int32(n_res) + rs
-    comp = es & (tb > 0)
-    cnt = jnp.zeros((mpad,), jnp.int32)
-    for c in range(0, mpad, chunk):
-        eq = (rkey[:, None] == rkey[c:c + chunk][None, :]) \
-            & comp[c:c + chunk][None, :]
-        cnt = cnt + jnp.sum(eq.astype(jnp.int32), axis=1)
-    dirty = comp & (cnt >= 2)
-    clean = es & ~dirty
+    comp = (es & (tb > 0)).astype(jnp.int32)
+    row = jax.lax.broadcasted_iota(jnp.int32, idx_out.shape, 0)
+    cnt = jnp.zeros(idx_out.shape, jnp.int32)
+    for rj in range(wrows):
+        krow = rkey[rj:rj + 1]
+        c = jnp.zeros((1, LANES), jnp.int32)
+        for ri in range(wrows):
+            kcol = rkey[ri:ri + 1].reshape(LANES, 1)
+            ccol = comp[ri:ri + 1].reshape(LANES, 1)
+            c = c + jnp.sum(jnp.where(kcol == krow, ccol, 0), axis=0,
+                            keepdims=True)
+        cnt = jnp.where(row == rj, c, cnt)
+    clean = es & ~((comp != 0) & (cnt >= 2))
 
     # step 3: same-kind grouping of the clean lanes (stable in window order)
-    gkey = jnp.where(clean, jnp.clip(kind_w, 0, n_kinds - 1),
-                     jnp.int32(n_kinds))
-    gidx = pos
-    kk = 2
-    while kk <= mpad:
-        jj = kk // 2
-        while jj >= 1:
-            def gpairs(x):
-                return x.reshape(mpad // (2 * jj), 2, jj)
+    gkey = jnp.where(clean, jnp.clip(kind_w, 0, n_kinds - 1), n_kinds)
+    (_, gidx), _ = _bitonic([gkey, pos])
 
-            kp, ip = gpairs(gkey), gpairs(gidx)
-            glo_i = jax.lax.broadcasted_iota(
-                jnp.int32, (mpad // (2 * jj), 1, jj), 0)
-            glo_r = jax.lax.broadcasted_iota(
-                jnp.int32, (mpad // (2 * jj), 1, jj), 2)
-            gascend = ((glo_i * (2 * jj) + glo_r) & kk) == 0
+    # step 4: release ranks off the free_tail ring cursor
+    rel = (tail_ref[...] + _exclusive_cumsum(es.astype(jnp.int32))) \
+        % jnp.int32(cap)
 
-            k_lo, k_hi = kp[:, :1], kp[:, 1:]
-            i_lo, i_hi = ip[:, :1], ip[:, 1:]
-            gle = (k_lo < k_hi) | ((k_lo == k_hi) & (i_lo < i_hi))
-            gswap = jnp.where(gascend, ~gle, gle)
-
-            def gmix(lo, hi):
-                return jnp.concatenate([jnp.where(gswap, hi, lo),
-                                        jnp.where(gswap, lo, hi)],
-                                       axis=1).reshape(mpad)
-
-            gkey, gidx = gmix(k_lo, k_hi), gmix(i_lo, i_hi)
-            jj //= 2
-        kk *= 2
-
-    # step 4: release ranks off the SMEM-resident free_tail cursor
-    w = es.astype(jnp.int32)
-    x = w
-    sh = 1
-    while sh < mpad:
-        x = x + jnp.concatenate([jnp.zeros((sh,), jnp.int32), x[:-sh]])
-        sh *= 2
-    rel = (tail_ref[0, 0] + (x - w)) % jnp.int32(cap)
-
-    idx_out[0] = idx[:mpad]
-    safe_out[0] = es.astype(jnp.int32)
-    time_out[0] = time_w
-    seq_out[0] = s[:mpad]
-    kind_out[0] = kind_w
-    src_out[0] = carry[3][:mpad]
-    dst_out[0] = carry[4][:mpad]
-    ctx_out[0] = carry[5][:mpad]
-    valid_out[0] = carry[6][:mpad]
+    idx_out[...] = idx[:wrows]
+    safe_out[...] = es.astype(jnp.int32)
+    time_out[...] = carry[1]
+    seq_out[...] = s[:wrows]
+    kind_out[...] = kind_w
+    src_out[...] = carry[3]
+    dst_out[...] = carry[4]
+    ctx_out[...] = carry[5]
+    valid_out[...] = carry[6]
     for p in range(n_pay):
-        pay_out[p] = carry[9 + p][:mpad]
-    clean_out[0] = clean.astype(jnp.int32)
-    order_out[0] = gidx
-    rel_out[0] = rel
+        pay_out[p] = carry[9 + p]
+    clean_out[...] = clean.astype(jnp.int32)
+    order_out[...] = gidx
+    rel_out[...] = rel
 
 
 def fused_select(time_key: jax.Array, seq: jax.Array, safe: jax.Array,
@@ -557,69 +493,56 @@ def fused_select(time_key: jax.Array, seq: jax.Array, safe: jax.Array,
     ``pallas_call``, intermediates never leaving VMEM. ``table_id``/``res``
     are the pool-wide conflict key columns (the engine precomputes the two
     registry gathers, the kernel has no table access); ``free_tail`` is the
-    pool's ring cursor, kept in SMEM on TPU. Lanes where ``exec_safe`` is
-    False carry the sorted slot's raw fields, exactly like the XLA gather —
-    the engine masks them everywhere.
+    pool's ring cursor. Lanes where ``exec_safe`` is False carry the sorted
+    slot's raw fields, exactly like the XLA gather — the engine masks them
+    everywhere.
     """
     del n_tables  # bounds the stitched twins' key space; the pairwise count
     #               needs no sentinel span
     cap = time_key.shape[0]
     m = max(min(exec_cap, cap), 1)
-    n = 1 << max((cap - 1).bit_length(), 1)
-    mpad = 1 << max((m - 1).bit_length(), 1)
+    n = _pow2_tile(cap)
+    wshape = (_pow2_tile(m) // LANES, LANES)
     n_pay = payload.shape[1]
-    chunk = min(mpad, 256)
 
     def pad(xv, fill):
-        return jnp.full((n,), fill, jnp.int32).at[:cap].set(
-            xv.astype(jnp.int32))[None]
+        return _to_tile(xv, n, fill)
 
     args = [pad(time_key, I32_MAX), pad(seq, I32_MAX), pad(safe, 0),
             pad(time, 0), pad(kind, 0), pad(src, 0), pad(dst, 0),
             pad(ctx, 0), pad(valid, 0), pad(table_id, 0), pad(res, 0)]
-    payp = jnp.zeros((n_pay, n), payload.dtype).at[:, :cap].set(payload.T)
-    tailp = jnp.asarray(free_tail, jnp.int32).reshape(1, 1)
+    payp = jnp.zeros((n_pay, n), payload.dtype).at[:, :cap].set(
+        payload.T).reshape(n_pay, n // LANES, LANES)
+    tailp = jnp.broadcast_to(jnp.asarray(free_tail, jnp.int32), (1, LANES))
 
-    def vec(w):
-        return pl.BlockSpec((1, w), lambda i: (0, 0))
-
-    if interpret:
-        tail_spec = vec(1)
-    else:
-        # compiled lane: the ring cursor is a scalar block in SMEM (lazy
-        # import — pltpu only resolves on a TPU-capable install)
-        from jax.experimental.pallas import tpu as pltpu
-        tail_spec = pl.BlockSpec(memory_space=pltpu.SMEM)
-
-    kernel = functools.partial(_fused_select_kernel, n=n, m=m, mpad=mpad,
-                               cap=cap, n_kinds=n_kinds, n_res=n_res,
-                               n_pay=n_pay, chunk=chunk)
+    kernel = functools.partial(_fused_select_kernel, m=m, cap=cap,
+                               n_kinds=n_kinds, n_res=n_res, n_pay=n_pay)
+    tile = jax.ShapeDtypeStruct(wshape, jnp.int32)
     outs = pl.pallas_call(
         kernel,
-        grid=(1,),
-        in_specs=[vec(n)] * 11
-        + [pl.BlockSpec((n_pay, n), lambda i: (0, 0)), tail_spec],
-        out_specs=[vec(mpad)] * 9
-        + [pl.BlockSpec((n_pay, mpad), lambda i: (0, 0))] + [vec(mpad)] * 3,
-        out_shape=[jax.ShapeDtypeStruct((1, mpad), jnp.int32)] * 9
-        + [jax.ShapeDtypeStruct((n_pay, mpad), payload.dtype)]
-        + [jax.ShapeDtypeStruct((1, mpad), jnp.int32)] * 3,
+        out_shape=[tile] * 9
+        + [jax.ShapeDtypeStruct((n_pay,) + wshape, payload.dtype)]
+        + [tile] * 3,
         interpret=interpret,
     )(*args, payp, tailp)
     (idxo, safeo, timeo, seqo, kindo, srco, dsto, ctxo, valido, payo,
      cleano, ordero, relo) = outs
+
+    def flat(x):
+        return x.reshape(-1)[:m]
+
     return FusedSelect(
-        exec_idx=idxo[0, :m],
-        exec_safe=safeo[0, :m] != 0,
-        time=timeo[0, :m],
-        seq=seqo[0, :m],
-        kind=kindo[0, :m],
-        src=srco[0, :m],
-        dst=dsto[0, :m],
-        ctx=ctxo[0, :m],
-        payload=payo[:, :m].T,
-        valid=valido[0, :m] != 0,
-        clean=cleano[0, :m] != 0,
-        order=ordero[0, :m],
-        rel_pos=relo[0, :m],
+        exec_idx=flat(idxo),
+        exec_safe=flat(safeo) != 0,
+        time=flat(timeo),
+        seq=flat(seqo),
+        kind=flat(kindo),
+        src=flat(srco),
+        dst=flat(dsto),
+        ctx=flat(ctxo),
+        payload=payo.reshape(n_pay, -1)[:, :m].T,
+        valid=flat(valido) != 0,
+        clean=flat(cleano) != 0,
+        order=flat(ordero),
+        rel_pos=flat(relo),
     )

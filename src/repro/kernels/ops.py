@@ -1,10 +1,13 @@
 """jit'd public wrappers for the Pallas kernels.
 
-Dispatch policy: on TPU backends the compiled kernels run natively; everywhere
-else (this CPU container, unit tests) ``interpret=True`` executes the same kernel
-bodies in Python for correctness validation against ref.py. The model zoo calls
-these through cfg.use_flash / engine select_fn hooks, so the XLA fallbacks and
-the kernels are interchangeable implementations of identical math.
+Dispatch policy: on a TPU backend the kernels are compiled by Mosaic; on any
+other backend (the CPU tests) ``interpret=True`` runs the same kernel bodies
+for correctness validation against ref.py. Interpret mode says nothing about
+the chip: ``tests/test_tpu_compile.py`` compiles the engine's kernels for a
+described TPU v5e, and ``chip_smoke.py`` refuses to run off a TPU. The model
+zoo calls these through cfg.use_flash / engine select_fn hooks, so the XLA
+fallbacks and the kernels are interchangeable implementations of identical
+math.
 """
 from __future__ import annotations
 
@@ -79,8 +82,8 @@ def fused_select(time_key, seq, safe, time, kind, src, dst, ctx, payload,
     """The superstep megakernel: the whole window front-end in one call.
 
     Fuses select + gather + conflict mask + group_by_kind + release ranks
-    (kernels.event_select.fused_select) with the free-ring cursor in SMEM on
-    TPU. Engine fused_fn hook — ``spec.fused_select=True`` binds it as
+    (kernels.event_select.fused_select). Engine fused_fn hook —
+    ``spec.fused_select=True`` binds it as
 
         functools.partial(ops.fused_select, n_kinds=registry.n_kinds,
                           n_res=registry.max_rows(world),

@@ -218,7 +218,13 @@ def run_distributed(args):
     from repro.core.components import DATA_WRITE, FLOW_START, JOB_SUBMIT
     from repro.launch.mesh import make_sim_mesh
 
-    n_dev = min(len(jax.devices()), 8)
+    devs = jax.devices()
+    n_dev = min(len(devs), 8)
+    if n_dev < 2:
+        # the forced host-device count only applies to the CPU backend: a
+        # one-chip host cannot give the mesh this mode exists to exercise
+        raise SystemExit(f"distributed needs more than one device; the "
+                         f"{devs[0].platform} backend has {len(devs)}")
     n = n_dev * args.agents_per_device
     b = ScenarioBuilder(max_cpu=4, queue_cap=16, max_link=4, max_flow=32)
     t0 = b.add_regional_center(n_cpu=2, cpu_power=10.0, disk=2000.0,
@@ -285,6 +291,7 @@ def run_distributed(args):
         extra += (f" streamed={ts.n_streamed}"
                   f" trace_drop={int(c[mon.C_TRACE_DROP])}")
     print(f"[distributed] agents={n} devices={n_dev} "
+          f"platform={devs[0].platform} "
           f"events={int(c[mon.C_EVENTS])} "
           f"windows={int(np.asarray(st.windows)[0])} "
           f"remote_msgs={int(c[mon.C_MSGS_REMOTE])}" + extra)
@@ -382,8 +389,9 @@ def run_catalog(args):
     if args.devices is not None:
         have = jax.devices()
         if args.devices > len(have):
-            raise SystemExit(f"--devices {args.devices} > available "
-                             f"{len(have)} (set XLA_FLAGS="
+            raise SystemExit(f"--devices {args.devices} > the {len(have)} "
+                             f"available on the {have[0].platform} backend "
+                             f"(on CPU, set XLA_FLAGS="
                              f"--xla_force_host_platform_device_count=N)")
         devices = have[: args.devices]
 
@@ -430,7 +438,8 @@ def run_catalog(args):
     cn = np.asarray(st.counters)  # (A, N) — or (R, A, N) for ensembles
     c = cn.sum(axis=tuple(range(cn.ndim - 1)))
     print(f"[run] {args.name} driver={res.driver} devices={res.devices} "
-          f"attempts={res.attempts} events={int(c[mon.C_EVENTS])} "
+          f"platform={jax.devices()[0].platform} attempts={res.attempts} "
+          f"events={int(c[mon.C_EVENTS])} "
           f"windows={int(np.asarray(st.windows).reshape(-1)[0])} "
           f"preempt={res.counts['PREEMPT']} resume={res.counts['RESUME']} "
           f"reshard={res.counts['RESHARD']}")
@@ -637,4 +646,8 @@ def main():
 
 
 if __name__ == "__main__":
+    # the CLI entry keeps its compiles across runs; in-process callers of
+    # main() (the tests) leave JAX's cache settings alone
+    from repro.launch import compile_cache
+    compile_cache.enable()
     main()

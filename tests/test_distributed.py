@@ -63,6 +63,34 @@ print(json.dumps(checks))
     assert not failed, failed
 
 
+@pytest.mark.parametrize("platform,n_dev,env,refused", [
+    ("tpu", 4, None, True),
+    ("tpu", 4, "1", False),
+    ("tpu", 1, None, False),
+    ("cpu", 4, None, False),
+])
+def test_multichip_tpu_mesh_refused_unless_asked(monkeypatch, platform, n_dev,
+                                                 env, refused):
+    """A mesh over several TPU chips raises a clear error unless
+    REPRO_MULTICHIP_TPU=1; one chip and host devices run as before."""
+    from types import SimpleNamespace
+    from repro.core.engine import MULTICHIP_TPU_ENV, Engine
+    if env is None:
+        monkeypatch.delenv(MULTICHIP_TPU_ENV, raising=False)
+    else:
+        monkeypatch.setenv(MULTICHIP_TPU_ENV, env)
+    devices = np.array([SimpleNamespace(platform=platform)] * n_dev,
+                       dtype=object)
+    mesh = SimpleNamespace(axis_names=("agents",), devices=devices)
+    eng = SimpleNamespace(spec=SimpleNamespace(n_agents=8))
+    if refused:
+        with pytest.raises(RuntimeError, match=MULTICHIP_TPU_ENV):
+            Engine._dist_axes(eng, mesh)
+    else:
+        axes = Engine._dist_axes(eng, mesh)
+        assert (axes.n_shards, axes.n_lanes) == (n_dev, 8 // n_dev)
+
+
 # The pinned acceptance cases: one with cross-shard event migration, one with
 # the adaptive per-shard width ladder actually moving rungs (verified: this
 # scenario spills at width 1 and climbs through every rung).

@@ -366,7 +366,7 @@ if HAVE_HYPOTHESIS:
     )
 
     @settings(max_examples=8, deadline=None)
-    @given(stream_params)
+    @given(p=stream_params)
     def test_streamed_equals_buffered_equals_oracle(p, oracle, buffered_ref):
         """The tentpole property: for any drain cadence, ring size >= width,
         static or adaptive width, the streamed trace is byte-identical to

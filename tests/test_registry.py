@@ -559,6 +559,39 @@ def test_cache_multi_agent_owner_wins_sync():
     np.testing.assert_array_equal(np.asarray(ow.cache_keys), w.cache_keys)
 
 
+def test_owner_wins_sync_keeps_float_bits():
+    """The owner's f32 row arrives bit for bit — -0.0 and denormals (int
+    bit patterns) included, which a float sum would turn into +0.0 / 0."""
+    from repro.core.registry import registry_of
+    (world, own, _ev, _spec), _ = build_churn_scenario(
+        n_caches=5, n_keys=2, n_rounds=4, n_agents=2)
+    reg = registry_of(world)
+    specials = np.array([-0.0, 1.0, 0.0], np.float32).view(np.int32)
+    specials = np.concatenate([specials, [1, 37, -3]]).astype(np.int32)
+    checked = 0
+    for comp in reg._components.values():
+        owner = np.asarray(world.lp_agent)[np.asarray(
+            getattr(own, comp.own_field))]
+        for fname, fs in comp.fields.items():
+            x = np.asarray(getattr(world, fname))
+            if not fs.mutable or x.dtype != np.float32:
+                continue
+            bits = np.resize(specials, x.size).reshape(x.shape)
+            setting = world._replace(**{fname: jnp.asarray(bits.view(
+                np.float32))})
+            stacked = jax.tree.map(lambda v: jnp.stack([v, v]), setting)
+            out = jax.vmap(lambda w: reg.sync_world(w, own, "a"),
+                           axis_name="a")(stacked)
+            got = np.asarray(getattr(out, fname)).view(np.int32)
+            mine = (owner >= 0) & (owner < 2)
+            mine = mine.reshape(mine.shape + (1,) * (x.ndim - 1))
+            want = np.where(mine, bits, 0)
+            np.testing.assert_array_equal(got[0], want, err_msg=fname)
+            np.testing.assert_array_equal(got[1], want, err_msg=fname)
+            checked += 1
+    assert checked
+
+
 # ---------------------------------------------------------------------------
 # Trace-buffer overflow: counted + loud
 # ---------------------------------------------------------------------------
