@@ -103,7 +103,6 @@ MULTICHIP_TPU_ENV = "REPRO_MULTICHIP_TPU"
 
 _shard_map = functools.partial(jax.shard_map, check_vma=False)
 
-
 class ShardAxes(NamedTuple):
     """The shard_map x vmap agent packing of ``run_distributed``.
 
@@ -235,6 +234,7 @@ class EngineState(NamedTuple):
 class Engine:
     """Binds a built scenario to the superstep program."""
 
+    @mon.span("engine.build")
     def __init__(self, world: World, own: WorldOwnership,
                  init_events: ev.EventBatch, spec: ScenarioSpec,
                  trace_cap: int = 0,
@@ -360,6 +360,7 @@ class Engine:
         self._jit_cache: dict = {}
 
     # ------------------------------------------------------------------ init
+    @mon.span("engine.init_state")
     def init_state(self) -> EngineState:
         """Stacked (A, ...) initial state; initial events homed to owner agents."""
         A = self.spec.n_agents
@@ -436,50 +437,56 @@ class Engine:
             # so the ring never overwrites an un-drained row (C_TRACE_DROP
             # stays 0) as long as the ring holds one window (checked by the
             # streaming drivers).
-            tcap = st.trace.shape[0]
-            pending = st.trace_n - st.trace_tail
-            do = ((pending + jnp.int32(xcap) > tcap)
-                  | (st.windows % jnp.int32(self.drain_every) == 0))
-            io_callback(self._on_trace_drain, None, me, st.trace_tail,
-                        jnp.where(do, pending, 0), st.trace, ordered=False)
-            st = st._replace(trace_tail=jnp.where(do, st.trace_n,
-                                                  st.trace_tail))
+            with mon.stage("drain"):
+                tcap = st.trace.shape[0]
+                pending = st.trace_n - st.trace_tail
+                do = ((pending + jnp.int32(xcap) > tcap)
+                      | (st.windows % jnp.int32(self.drain_every) == 0))
+                io_callback(self._on_trace_drain, None, me, st.trace_tail,
+                            jnp.where(do, pending, 0), st.trace,
+                            ordered=False)
+                st = st._replace(trace_tail=jnp.where(do, st.trace_n,
+                                                      st.trace_tail))
 
         # 1-2. GVT + safe mask (C2)
-        lmin = sync.local_min_per_ctx(pool, spec.n_ctx)
-        gvt = sync.global_min(lmin, axis_names(axis))
-        horizon = sync.horizons(gvt, spec.lookahead, spec.t_end)
-        done = sync.all_done(gvt, spec.t_end)
-        safe = sync.safe_mask(pool, horizon)
+        with mon.stage("gvt"):
+            lmin = sync.local_min_per_ctx(pool, spec.n_ctx)
+            gvt = sync.global_min(lmin, axis_names(axis))
+            horizon = sync.horizons(gvt, spec.lookahead, spec.t_end)
+            done = sync.all_done(gvt, spec.t_end)
+            safe = sync.safe_mask(pool, horizon)
 
         # 3. order (time, seq) + compact: unsafe slots sort to the back, and only
         # the first exec_cap gather indices (the earliest safe slots) are kept
-        time_key = jnp.where(safe, pool.time, ev.T_INF)
-        if spec.fused_select:
-            # fused front-end: select + gather + conflict + group + release
-            # ranks in ONE fused_fn call (the Pallas megakernel by default).
-            # The conflict key columns are precomputed pool-wide — two cheap
-            # registry gathers; clip-then-gather commutes with the gather the
-            # stitched path does per window, so the bytes match exactly.
-            tbl_pool = jnp.asarray(self.registry.kind_table, jnp.int32)[
-                jnp.clip(pool.kind, 0, self.registry.n_kinds - 1)]
-            res_pool = world.lp_res[jnp.clip(pool.dst, 0, spec.n_lp - 1)]
-            fs = self.fused_fn(time_key, pool.seq, safe, pool.time, pool.kind,
-                               pool.src, pool.dst, pool.ctx, pool.payload,
-                               pool.valid, tbl_pool, res_pool, pool.free_tail,
-                               xcap)
-            exec_idx, exec_safe = fs.exec_idx, fs.exec_safe
-            cand = ev.EventBatch(time=fs.time, seq=fs.seq, kind=fs.kind,
-                                 src=fs.src, dst=fs.dst, ctx=fs.ctx,
-                                 payload=fs.payload, valid=fs.valid)
-            pre = (fs.clean, fs.order)
-            rel_pos = fs.rel_pos
-        else:
-            exec_idx = self.select_fn(time_key, pool.seq, xcap)
-            exec_safe = sync.exec_selection_ring(safe, exec_idx)
-            cand = ev.gather(pool, exec_idx)
-            pre = None
-            rel_pos = None
+        with mon.stage("select"):
+            time_key = jnp.where(safe, pool.time, ev.T_INF)
+            if spec.fused_select:
+                # fused front-end: select + gather + conflict + group +
+                # release ranks in ONE fused_fn call (the Pallas megakernel by
+                # default). The conflict key columns are precomputed
+                # pool-wide — two cheap registry gathers; clip-then-gather
+                # commutes with the gather the stitched path does per window,
+                # so the bytes match exactly.
+                tbl_pool = jnp.asarray(self.registry.kind_table, jnp.int32)[
+                    jnp.clip(pool.kind, 0, self.registry.n_kinds - 1)]
+                res_pool = world.lp_res[jnp.clip(pool.dst, 0,
+                                                 spec.n_lp - 1)]
+                fs = self.fused_fn(time_key, pool.seq, safe, pool.time,
+                                   pool.kind, pool.src, pool.dst, pool.ctx,
+                                   pool.payload, pool.valid, tbl_pool,
+                                   res_pool, pool.free_tail, xcap)
+                exec_idx, exec_safe = fs.exec_idx, fs.exec_safe
+                cand = ev.EventBatch(time=fs.time, seq=fs.seq, kind=fs.kind,
+                                     src=fs.src, dst=fs.dst, ctx=fs.ctx,
+                                     payload=fs.payload, valid=fs.valid)
+                pre = (fs.clean, fs.order)
+                rel_pos = fs.rel_pos
+            else:
+                exec_idx = self.select_fn(time_key, pool.seq, xcap)
+                exec_safe = sync.exec_selection_ring(safe, exec_idx)
+                cand = ev.gather(pool, exec_idx)
+                pre = None
+                rel_pos = None
 
         # 4. execute the window: grouped vectorized dispatch (default) or the
         # sequential fold — byte-identical results either way; safe events
@@ -490,53 +497,60 @@ class Engine:
             world, counters, cand, exec_safe, st.trace, st.trace_n,
             ring=stream_trace, pre=pre)
         if stream_trace:
-            # ring overwrite accounting: rows written this window on top of
-            # un-drained ones (structurally 0 under the drain invariant above;
-            # exact when a caller bypasses the ring-size check)
-            pb = st.trace_n - st.trace_tail
-            pa = trace_n - st.trace_tail
-            tcap = st.trace.shape[0]
-            counters = mon.bump(
-                counters, mon.C_TRACE_DROP,
-                jnp.maximum(pa - tcap, 0) - jnp.maximum(pb - tcap, 0))
+            with mon.stage("trace"):
+                # ring overwrite accounting: rows written this window on top
+                # of un-drained ones (structurally 0 under the drain
+                # invariant above; exact when a caller bypasses the
+                # ring-size check)
+                pb = st.trace_n - st.trace_tail
+                pa = trace_n - st.trace_tail
+                tcap = st.trace.shape[0]
+                counters = mon.bump(
+                    counters, mon.C_TRACE_DROP,
+                    jnp.maximum(pa - tcap, 0) - jnp.maximum(pb - tcap, 0))
 
-        n_processed = jnp.sum(exec_safe.astype(jnp.int32))
-        n_spill = jnp.sum(safe.astype(jnp.int32)) - n_processed
-        counters = mon.bump(counters, mon.C_EVENTS, n_processed)
-        counters = mon.bump(counters, mon.C_EXEC_SPILL, n_spill)
-        counters = mon.bump(counters, mon.C_WINDOWS, 1)
-        # slot reclaim: ring mode pushes the executed slots onto the free
-        # ring's tail (O(exec_cap)); ref mode keeps the pool-wide pop mask
-        if spec.insert_mode == "ring":
-            counters = mon.bump(
-                counters, mon.C_RING_WRAP,
-                pool.free_tail + n_processed >= jnp.int32(spec.pool_cap))
-            pool = ev.release(pool, exec_idx, exec_safe, pos=rel_pos)
-        else:
-            slot_mask, _ = sync.exec_selection(safe, exec_idx)
-            pool = ev.pop_mask_ref(pool, slot_mask)
+        with mon.stage("release"):
+            n_processed = jnp.sum(exec_safe.astype(jnp.int32))
+            n_spill = jnp.sum(safe.astype(jnp.int32)) - n_processed
+            counters = mon.bump(counters, mon.C_EVENTS, n_processed)
+            counters = mon.bump(counters, mon.C_EXEC_SPILL, n_spill)
+            counters = mon.bump(counters, mon.C_WINDOWS, 1)
+            # slot reclaim: ring mode pushes the executed slots onto the free
+            # ring's tail (O(exec_cap)); ref mode keeps the pool-wide pop mask
+            if spec.insert_mode == "ring":
+                counters = mon.bump(
+                    counters, mon.C_RING_WRAP,
+                    pool.free_tail + n_processed >= jnp.int32(spec.pool_cap))
+                pool = ev.release(pool, exec_idx, exec_safe, pos=rel_pos)
+            else:
+                slot_mask, _ = sync.exec_selection(safe, exec_idx)
+                pool = ev.pop_mask_ref(pool, slot_mask)
 
-        # processed LPs drop back to WAITING at window end (thread states -> data)
-        world = world._replace(
-            lp_state=jnp.where(world.lp_state == 2, 3, world.lp_state))
+            # processed LPs drop back to WAITING at window end (thread
+            # states -> data)
+            world = world._replace(
+                lp_state=jnp.where(world.lp_state == 2, 3, world.lp_state))
 
         # 5-6. route + insert
         pool, counters = self._route_and_insert(world, pool, counters, emits, axis)
 
         # 7. replicated-state sync (C4) — field lists generated by the registry
-        world = self.registry.sync_world(world, self.own, axis_names(axis))
+        with mon.stage("sync"):
+            world = self.registry.sync_world(world, self.own, axis_names(axis))
 
         # pool-lifecycle gauges: the occupancy/headroom signals the adaptive
         # exec policy reads (O(1) off the ring's free count in either mode)
-        counters = mon.gauge(counters, mon.C_POOL_OCC, ev.occupancy(pool))
-        counters = mon.gauge(counters, mon.C_POOL_FREE, pool.free_count)
+        with mon.stage("gauges"):
+            counters = mon.gauge(counters, mon.C_POOL_OCC, ev.occupancy(pool))
+            counters = mon.gauge(counters, mon.C_POOL_FREE, pool.free_count)
 
         if stream_metrics:
             # end-of-window metrics snapshot: every agent ships its counter
             # vector; the host sink assembles a fleet view per window and
             # emits JSON lines on the configured cadence
-            io_callback(self._on_metrics, None, me, st.windows + 1,
-                        jnp.max(horizon), counters, ordered=False)
+            with mon.stage("drain"):
+                io_callback(self._on_metrics, None, me, st.windows + 1,
+                            jnp.max(horizon), counters, ordered=False)
 
         return EngineState(world=world, pool=pool, counters=counters,
                            t_now=jnp.max(horizon), done=done,
@@ -601,24 +615,27 @@ class Engine:
             # Bounded overflow is counted (C_TRACE_DROP), never silent —
             # merged_engine_trace refuses to return a truncated trace; ring
             # overwrites are accounted at the window boundary (_superstep).
-            tcap = trace.shape[0]
-            trow = jnp.stack([e.time, e.seq, e.kind, e.dst])
-            if ring:
-                tidx = jnp.where(is_safe, trace_n % tcap, tcap)
-                trace = trace.at[tidx].set(trow, mode="drop")
-            else:
-                tidx = jnp.where(is_safe & (trace_n < tcap), trace_n, tcap)
-                trace = trace.at[tidx].set(trow, mode="drop")
-                if self.trace_cap > 0:
-                    counters = mon.bump(
-                        counters, mon.C_TRACE_DROP,
-                        jnp.where(is_safe & (trace_n >= tcap), 1, 0))
-            trace_n = trace_n + jnp.where(is_safe, 1, 0)
+            with mon.stage("trace"):
+                tcap = trace.shape[0]
+                trow = jnp.stack([e.time, e.seq, e.kind, e.dst])
+                if ring:
+                    tidx = jnp.where(is_safe, trace_n % tcap, tcap)
+                    trace = trace.at[tidx].set(trow, mode="drop")
+                else:
+                    tidx = jnp.where(is_safe & (trace_n < tcap), trace_n,
+                                     tcap)
+                    trace = trace.at[tidx].set(trow, mode="drop")
+                    if self.trace_cap > 0:
+                        counters = mon.bump(
+                            counters, mon.C_TRACE_DROP,
+                            jnp.where(is_safe & (trace_n >= tcap), 1, 0))
+                trace_n = trace_n + jnp.where(is_safe, 1, 0)
             return (world, counters, emits, emit_n, trace, trace_n), None
 
         carry0 = (world, counters, emit0, jnp.int32(0), trace0, trace_n0)
-        (world, counters, emits, _, trace, trace_n), _ = jax.lax.scan(
-            body, carry0, (cand, exec_safe))
+        with mon.stage("dispatch"):
+            (world, counters, emits, _, trace, trace_n), _ = jax.lax.scan(
+                body, carry0, (cand, exec_safe))
         return world, counters, emits, trace, trace_n
 
     # -------------------------------------------- step 4: vectorized dispatch
@@ -636,110 +653,120 @@ class Engine:
         spec = self.spec
         xcap = cand.time.shape[0]
 
-        if pre is None:
-            # conflict detection on the delta contract's declared rows: two
-            # safe slots collide iff they address the same (component table,
-            # lp_res row)
-            table_id = jnp.asarray(self.registry.kind_table, jnp.int32)[
-                jnp.clip(cand.kind, 0, self.registry.n_kinds - 1)]
-            res = world.lp_res[jnp.clip(cand.dst, 0, spec.n_lp - 1)]
-            dirty = sync.conflict_mask(exec_safe, table_id, res,
-                                       n_res=self._n_res,
-                                       n_tables=self.registry.n_tables)
-            clean = exec_safe & ~dirty
+        with mon.stage("dispatch"):
+            if pre is None:
+                # conflict detection on the delta contract's declared rows:
+                # two safe slots collide iff they address the same
+                # (component table, lp_res row)
+                table_id = jnp.asarray(self.registry.kind_table, jnp.int32)[
+                    jnp.clip(cand.kind, 0, self.registry.n_kinds - 1)]
+                res = world.lp_res[jnp.clip(cand.dst, 0, spec.n_lp - 1)]
+                dirty = sync.conflict_mask(exec_safe, table_id, res,
+                                           n_res=self._n_res,
+                                           n_tables=self.registry.n_tables)
+                clean = exec_safe & ~dirty
 
-            # batched phase: group the clean rows by kind, dispatch once. The
-            # grouped order keeps same-kind lanes contiguous (coherent
-            # segments on wide-vector backends); the merge itself is
-            # order-independent under the disjoint-write contract, and a
-            # vmapped switch traces every handler per lane either way — on
-            # CPU the permutation costs a few percent of the window and buys
-            # layout, not fewer handler evals.
-            order, _rank, _counts = self.group_fn(cand.kind, clean)
-        else:
-            # fused front-end (spec.fused_select): the megakernel already
-            # computed the conflict mask and grouping in-VMEM; dirty is
-            # recoverable because clean == exec_safe & ~dirty with
-            # dirty ⊆ exec_safe
-            clean, order = pre
-            dirty = exec_safe & ~clean
-        rows_g = jax.tree.map(lambda x: x[order], cand)
-        clean_g = clean[order]
-        batch_fn = (apply_handler_batch if spec.merge_mode == "delta"
-                    else apply_handler_batch_dense)
-        world, cdelta, emits_g = batch_fn(self.table, world, rows_g, clean_g)
-        counters = counters + cdelta
-        counters = mon.bump(counters, mon.C_BATCH_EXEC,
-                            jnp.sum(clean.astype(jnp.int32)))
+                # batched phase: group the clean rows by kind, dispatch once.
+                # The grouped order keeps same-kind lanes contiguous (coherent
+                # segments on wide-vector backends); the merge itself is
+                # order-independent under the disjoint-write contract, and a
+                # vmapped switch traces every handler per lane either way —
+                # on CPU the permutation costs a few percent of the window
+                # and buys layout, not fewer handler evals.
+                order, _rank, _counts = self.group_fn(cand.kind, clean)
+            else:
+                # fused front-end (spec.fused_select): the megakernel already
+                # computed the conflict mask and grouping in-VMEM; dirty is
+                # recoverable because clean == exec_safe & ~dirty with
+                # dirty ⊆ exec_safe
+                clean, order = pre
+                dirty = exec_safe & ~clean
+            rows_g = jax.tree.map(lambda x: x[order], cand)
+            clean_g = clean[order]
+            batch_fn = (apply_handler_batch if spec.merge_mode == "delta"
+                        else apply_handler_batch_dense)
+            world, cdelta, emits_g = batch_fn(self.table, world, rows_g,
+                                              clean_g)
+            counters = counters + cdelta
+            counters = mon.bump(counters, mon.C_BATCH_EXEC,
+                                jnp.sum(clean.astype(jnp.int32)))
 
-        # per-slot emit matrix in window order (grouped lanes scattered back)
-        emit_mat = jax.tree.map(lambda x: jnp.zeros_like(x).at[order].set(x),
-                                emits_g)
+            # per-slot emit matrix in window order (grouped lanes scattered
+            # back)
+            emit_mat = jax.tree.map(
+                lambda x: jnp.zeros_like(x).at[order].set(x), emits_g)
 
-        # conflict fallback: sequential fold compacted to the dirty slots
-        # (zero while_loop iterations on a conflict-free window)
-        n_dirty = jnp.sum(dirty.astype(jnp.int32))
-        counters = mon.bump(counters, mon.C_BATCH_FALLBACK, n_dirty)
-        pos = jnp.arange(xcap, dtype=jnp.int32)
-        dpos = jnp.sort(jnp.where(dirty, pos, xcap))
+        with mon.stage("fallback"):
+            # conflict fallback: sequential fold compacted to the dirty slots
+            # (zero while_loop iterations on a conflict-free window)
+            n_dirty = jnp.sum(dirty.astype(jnp.int32))
+            counters = mon.bump(counters, mon.C_BATCH_FALLBACK, n_dirty)
+            pos = jnp.arange(xcap, dtype=jnp.int32)
+            dpos = jnp.sort(jnp.where(dirty, pos, xcap))
 
-        def cond(carry):
-            return carry[0] < n_dirty
+            def cond(carry):
+                return carry[0] < n_dirty
 
-        def body(carry):
-            k, world, counters, emit_mat = carry
-            p = dpos[jnp.minimum(k, xcap - 1)]
-            row = jax.tree.map(lambda x: x[jnp.minimum(p, xcap - 1)], cand)
-            e = Ev(time=row.time, seq=row.seq, kind=row.kind,
-                   src=row.src, dst=row.dst, ctx=row.ctx,
-                   payload=row.payload)
-            active = k < n_dirty
+            def body(carry):
+                k, world, counters, emit_mat = carry
+                p = dpos[jnp.minimum(k, xcap - 1)]
+                row = jax.tree.map(lambda x: x[jnp.minimum(p, xcap - 1)], cand)
+                e = Ev(time=row.time, seq=row.seq, kind=row.kind,
+                       src=row.src, dst=row.dst, ctx=row.ctx,
+                       payload=row.payload)
+                active = k < n_dirty
 
-            def run(w, c):
-                w2, c2, out = apply_handler(self.table, w, c, e)
-                w2 = w2._replace(
-                    lp_lvt=w2.lp_lvt.at[e.dst].max(e.time),
-                    lp_state=w2.lp_state.at[e.dst].set(2),  # RUNNING
+                def run(w, c):
+                    w2, c2, out = apply_handler(self.table, w, c, e)
+                    w2 = w2._replace(
+                        lp_lvt=w2.lp_lvt.at[e.dst].max(e.time),
+                        lp_state=w2.lp_state.at[e.dst].set(2),  # RUNNING
+                    )
+                    return w2, c2, out
+
+                def skip(w, c):
+                    return w, c, ev.empty_batch(ev.MAX_EMIT)
+
+                world, counters, out = jax.lax.cond(active, run, skip,
+                                                    world, counters)
+                emit_mat = ev.EventBatch(
+                    time=emit_mat.time.at[p].set(out.time, mode="drop"),
+                    seq=emit_mat.seq.at[p].set(out.seq, mode="drop"),
+                    kind=emit_mat.kind.at[p].set(out.kind, mode="drop"),
+                    src=emit_mat.src.at[p].set(out.src, mode="drop"),
+                    dst=emit_mat.dst.at[p].set(out.dst, mode="drop"),
+                    ctx=emit_mat.ctx.at[p].set(out.ctx, mode="drop"),
+                    payload=emit_mat.payload.at[p].set(out.payload,
+                                                       mode="drop"),
+                    valid=emit_mat.valid.at[p].set(out.valid & active,
+                                                   mode="drop"),
                 )
-                return w2, c2, out
+                return k + 1, world, counters, emit_mat
 
-            def skip(w, c):
-                return w, c, ev.empty_batch(ev.MAX_EMIT)
+            _, world, counters, emit_mat = jax.lax.while_loop(
+                cond, body, (jnp.int32(0), world, counters, emit_mat))
 
-            world, counters, out = jax.lax.cond(active, run, skip,
-                                                world, counters)
-            emit_mat = ev.EventBatch(
-                time=emit_mat.time.at[p].set(out.time, mode="drop"),
-                seq=emit_mat.seq.at[p].set(out.seq, mode="drop"),
-                kind=emit_mat.kind.at[p].set(out.kind, mode="drop"),
-                src=emit_mat.src.at[p].set(out.src, mode="drop"),
-                dst=emit_mat.dst.at[p].set(out.dst, mode="drop"),
-                ctx=emit_mat.ctx.at[p].set(out.ctx, mode="drop"),
-                payload=emit_mat.payload.at[p].set(out.payload, mode="drop"),
-                valid=emit_mat.valid.at[p].set(out.valid & active,
-                                               mode="drop"),
-            )
-            return k + 1, world, counters, emit_mat
+        with mon.stage("trace"):
+            # trace in (time, seq) window order — independent of execution
+            # order. events.trace_append holds the position math (ring writes
+            # wrap under the streaming drain; bounded overflow is counted,
+            # never silent).
+            rows4 = jnp.stack([cand.time, cand.seq, cand.kind, cand.dst],
+                              axis=1)
+            trace, trace_n, clipped = ev.trace_append(
+                trace, trace_n, rows4, exec_safe, ring=ring,
+                rank_fn=self.trace_fn)
+            if not ring and self.trace_cap > 0:
+                counters = mon.bump(counters, mon.C_TRACE_DROP, clipped)
 
-        _, world, counters, emit_mat = jax.lax.while_loop(
-            cond, body, (jnp.int32(0), world, counters, emit_mat))
-
-        # trace in (time, seq) window order — independent of execution order.
-        # events.trace_append holds the position math (ring writes wrap under
-        # the streaming drain; bounded overflow is counted, never silent).
-        rows4 = jnp.stack([cand.time, cand.seq, cand.kind, cand.dst], axis=1)
-        trace, trace_n, clipped = ev.trace_append(
-            trace, trace_n, rows4, exec_safe, ring=ring,
-            rank_fn=self.trace_fn)
-        if not ring and self.trace_cap > 0:
-            counters = mon.bump(counters, mon.C_TRACE_DROP, clipped)
-
-        # segmented emit merge: flatten the per-slot matrix row-major (== the
-        # sequential append order) and compact into the window emit buffer
-        flat = jax.tree.map(
-            lambda x: x.reshape((xcap * ev.MAX_EMIT,) + x.shape[2:]), emit_mat)
-        emits, _n_emit, dropped = ev.compact_batch(flat, spec.emit_cap)
-        counters = mon.bump(counters, mon.C_DROP_POOL, dropped)
+            # segmented emit merge: flatten the per-slot matrix row-major
+            # (== the sequential append order) and compact into the window
+            # emit buffer
+            flat = jax.tree.map(
+                lambda x: x.reshape((xcap * ev.MAX_EMIT,) + x.shape[2:]),
+                emit_mat)
+            emits, _n_emit, dropped = ev.compact_batch(flat, spec.emit_cap)
+            counters = mon.bump(counters, mon.C_DROP_POOL, dropped)
         return world, counters, emits, trace, trace_n
 
     # ---------------------------------------------------------------- routing
@@ -774,84 +801,91 @@ class Engine:
         spec = self.spec
         A = axis.size if isinstance(axis, ShardAxes) else spec.n_agents
         if axis is None or A == 1:
-            pool, counters, dropped = self._insert(pool, counters, emits)
-            counters = mon.bump(counters, mon.C_DROP_POOL, dropped)
-            counters = mon.bump(counters, mon.C_LP_LOCAL,
-                                jnp.sum(emits.valid.astype(jnp.int32)))
+            with mon.stage("insert"):
+                pool, counters, dropped = self._insert(pool, counters, emits)
+                counters = mon.bump(counters, mon.C_DROP_POOL, dropped)
+                counters = mon.bump(counters, mon.C_LP_LOCAL,
+                                    jnp.sum(emits.valid.astype(jnp.int32)))
             return pool, counters
 
-        me = jax.lax.axis_index(axis_names(axis))
-        rcap = spec.route_cap
-        dst_agent = jnp.where(emits.valid, world.lp_agent[emits.dst], A)
+        with mon.stage("route"):
+            me = jax.lax.axis_index(axis_names(axis))
+            rcap = spec.route_cap
+            dst_agent = jnp.where(emits.valid, world.lp_agent[emits.dst], A)
 
-        # stable bucket ranks (route_fn hook; default XLA sort-based rank)
-        rank = self.route_fn(dst_agent)
+            # stable bucket ranks (route_fn hook; default XLA sort-based rank)
+            rank = self.route_fn(dst_agent)
 
-        ok = emits.valid & (rank < rcap)
-        counters = mon.bump(counters, mon.C_DROP_ROUTE,
-                            jnp.sum((emits.valid & ~ok).astype(jnp.int32)))
-        counters = mon.bump(
-            counters, mon.C_MSGS_REMOTE,
-            jnp.sum((ok & (dst_agent != me)).astype(jnp.int32)))
-        counters = mon.bump(
-            counters, mon.C_LP_LOCAL,
-            jnp.sum((ok & (dst_agent == me)).astype(jnp.int32)))
-        if migrate:
+            ok = emits.valid & (rank < rcap)
+            counters = mon.bump(counters, mon.C_DROP_ROUTE,
+                                jnp.sum((emits.valid & ~ok).astype(jnp.int32)))
             counters = mon.bump(
-                counters, mon.C_MIGRATE_OUT,
+                counters, mon.C_MSGS_REMOTE,
                 jnp.sum((ok & (dst_agent != me)).astype(jnp.int32)))
+            counters = mon.bump(
+                counters, mon.C_LP_LOCAL,
+                jnp.sum((ok & (dst_agent == me)).astype(jnp.int32)))
+            if migrate:
+                counters = mon.bump(
+                    counters, mon.C_MIGRATE_OUT,
+                    jnp.sum((ok & (dst_agent != me)).astype(jnp.int32)))
 
-        flat = jnp.where(ok, dst_agent * rcap + rank, A * rcap)  # OOB -> drop
+            # OOB -> drop
+            flat = jnp.where(ok, dst_agent * rcap + rank, A * rcap)
 
-        def scatter(col, fill):
-            buf = jnp.full((A * rcap,) + col.shape[1:], fill, col.dtype)
-            return buf.at[flat].set(col, mode="drop").reshape(
-                (A, rcap) + col.shape[1:])
+            def scatter(col, fill):
+                buf = jnp.full((A * rcap,) + col.shape[1:], fill, col.dtype)
+                return buf.at[flat].set(col, mode="drop").reshape(
+                    (A, rcap) + col.shape[1:])
 
-        if isinstance(axis, ShardAxes):
-            # all_to_all takes a single axis name, so the (shard x lane)
-            # exchange is staged: reshape the (A, rcap, ...) buffer to the
-            # shard-major (D, K, rcap, ...) packing, exchange shard blocks
-            # across the mesh, then lane blocks inside each shard. The
-            # flattened receive order is ascending global source agent —
-            # exactly the flat single-axis exchange's — so pool slot layouts
-            # (and hence traces/counters) stay byte-identical to run_local.
-            d, k = axis.n_shards, axis.n_lanes
+            if isinstance(axis, ShardAxes):
+                # all_to_all takes a single axis name, so the (shard x lane)
+                # exchange is staged: reshape the (A, rcap, ...) buffer to the
+                # shard-major (D, K, rcap, ...) packing, exchange shard blocks
+                # across the mesh, then lane blocks inside each shard. The
+                # flattened receive order is ascending global source agent —
+                # exactly the flat single-axis exchange's — so pool slot
+                # layouts (and hence traces/counters) stay byte-identical to
+                # run_local.
+                d, k = axis.n_shards, axis.n_lanes
 
-            def a2a(col):
-                x = col.reshape((d, k) + col.shape[1:])
-                x = jax.lax.all_to_all(x, axis.shard, split_axis=0,
-                                       concat_axis=0)
-                x = jax.lax.all_to_all(x, axis.lane, split_axis=1,
-                                       concat_axis=1)
-                return x.reshape(col.shape)
-        else:
-            a2a = functools.partial(jax.lax.all_to_all, axis_name=axis,
-                                    split_axis=0, concat_axis=0)
+                def a2a(col):
+                    x = col.reshape((d, k) + col.shape[1:])
+                    x = jax.lax.all_to_all(x, axis.shard, split_axis=0,
+                                           concat_axis=0)
+                    x = jax.lax.all_to_all(x, axis.lane, split_axis=1,
+                                           concat_axis=1)
+                    return x.reshape(col.shape)
+            else:
+                a2a = functools.partial(jax.lax.all_to_all, axis_name=axis,
+                                        split_axis=0, concat_axis=0)
 
-        # the payload's int32 fields ride as f32 bit patterns, and small ints
-        # are f32 denormals, which a cross-chip TPU all_to_all flushes to
-        # zero: exchange the raw bits as int32 and view them back
-        payload_bits = jax.lax.bitcast_convert_type(emits.payload, jnp.int32)
-        rx = ev.EventBatch(
-            time=a2a(scatter(emits.time, ev.T_INF)).reshape(A * rcap),
-            seq=a2a(scatter(emits.seq, 0)).reshape(A * rcap),
-            kind=a2a(scatter(emits.kind, 0)).reshape(A * rcap),
-            src=a2a(scatter(emits.src, 0)).reshape(A * rcap),
-            dst=a2a(scatter(emits.dst, 0)).reshape(A * rcap),
-            ctx=a2a(scatter(emits.ctx, 0)).reshape(A * rcap),
-            payload=jax.lax.bitcast_convert_type(
-                a2a(scatter(payload_bits, 0)), jnp.float32).reshape(
-                    A * rcap, ev.PAYLOAD),
-            valid=a2a(scatter(emits.valid, False)).reshape(A * rcap),
-        )
-        if migrate:
-            # received rows counted before insert: out/in balance is exact,
-            # and any overflow below is a C_DROP_POOL, not a silent loss
-            counters = mon.bump(counters, mon.C_MIGRATE_IN,
-                                jnp.sum(rx.valid.astype(jnp.int32)))
-        pool, counters, dropped = self._insert(pool, counters, rx)
-        counters = mon.bump(counters, mon.C_DROP_POOL, dropped)
+            # the payload's int32 fields ride as f32 bit patterns, and small
+            # ints are f32 denormals, which a cross-chip TPU all_to_all
+            # flushes to zero: exchange the raw bits as int32 and view them
+            # back
+            payload_bits = jax.lax.bitcast_convert_type(emits.payload,
+                                                        jnp.int32)
+            rx = ev.EventBatch(
+                time=a2a(scatter(emits.time, ev.T_INF)).reshape(A * rcap),
+                seq=a2a(scatter(emits.seq, 0)).reshape(A * rcap),
+                kind=a2a(scatter(emits.kind, 0)).reshape(A * rcap),
+                src=a2a(scatter(emits.src, 0)).reshape(A * rcap),
+                dst=a2a(scatter(emits.dst, 0)).reshape(A * rcap),
+                ctx=a2a(scatter(emits.ctx, 0)).reshape(A * rcap),
+                payload=jax.lax.bitcast_convert_type(
+                    a2a(scatter(payload_bits, 0)), jnp.float32).reshape(
+                        A * rcap, ev.PAYLOAD),
+                valid=a2a(scatter(emits.valid, False)).reshape(A * rcap),
+            )
+            if migrate:
+                # received rows counted before insert: out/in balance is exact,
+                # and any overflow below is a C_DROP_POOL, not a silent loss
+                counters = mon.bump(counters, mon.C_MIGRATE_IN,
+                                    jnp.sum(rx.valid.astype(jnp.int32)))
+        with mon.stage("insert"):
+            pool, counters, dropped = self._insert(pool, counters, rx)
+            counters = mon.bump(counters, mon.C_DROP_POOL, dropped)
         return pool, counters
 
     # -------------------------------------------------- host-streaming layer
@@ -880,8 +914,9 @@ class Engine:
         w = int(np.asarray(st.windows).reshape(-1)[0])
         if not ck.due(w):
             return
-        ck.save_sim(w, self._slice_state(st) if padded else st,
-                    engine=self, rung=rung)
+        with mon.span("engine.checkpoint", window=w):
+            ck.save_sim(w, self._slice_state(st) if padded else st,
+                        engine=self, rung=rung)
 
     def restore(self, step: int | None = None):
         """Load a checkpoint written by this engine's checkpointer.
@@ -895,7 +930,8 @@ class Engine:
         record sequence concatenates exactly across the boundary)."""
         if self.checkpointer is None:
             raise ValueError("no checkpointer attached to this engine")
-        return self.checkpointer.restore_sim(self, step=step)
+        with mon.span("engine.restore"):
+            return self.checkpointer.restore_sim(self, step=step)
 
     def _on_trace_drain(self, agent, start, count, ring):
         """io_callback target (host thread): forward a drained span."""
@@ -934,15 +970,16 @@ class Engine:
         makes every in-flight io_callback land before reassembly."""
         if not self._streaming:
             return st
-        getattr(jax, "effects_barrier", lambda: None)()
-        if self.trace_stream is not None:
-            self.trace_stream.finalize(np.asarray(st.trace),
-                                       np.asarray(st.trace_n),
-                                       np.asarray(st.trace_tail))
-        if self.metrics_stream is not None:
-            self.metrics_stream.finalize(np.asarray(st.counters),
-                                         np.asarray(st.windows),
-                                         np.asarray(st.t_now))
+        with mon.span("engine.finalize"):
+            getattr(jax, "effects_barrier", lambda: None)()
+            if self.trace_stream is not None:
+                self.trace_stream.finalize(np.asarray(st.trace),
+                                           np.asarray(st.trace_n),
+                                           np.asarray(st.trace_tail))
+            if self.metrics_stream is not None:
+                self.metrics_stream.finalize(np.asarray(st.counters),
+                                             np.asarray(st.windows),
+                                             np.asarray(st.t_now))
         return st
 
     def _run_hosted(self, max_windows: int,
@@ -967,15 +1004,23 @@ class Engine:
             st = self._pad_state(self.init_state() if state is None else state,
                                  axes.size)
             fn = self._dist_window_fn(mesh, width)
-        for _ in range(max_windows):
+        w0 = self._first_window(st)
+        for i in range(max_windows):
             if bool(np.asarray(st.done).all()):
                 break
-            st = fn(st)
-            self._checkpoint_window(st, padded=mesh is not None)
-            self._fire_window_hook(st)
+            with mon.span("engine.window", step_num=w0 + i):
+                st = fn(st)
+                self._checkpoint_window(st, padded=mesh is not None)
+                self._fire_window_hook(st)
         if mesh is not None:
             st = self._slice_state(st)
         return self._finalize_streams(st)
+
+    @staticmethod
+    def _first_window(st: EngineState) -> int:
+        """The window count a host-stepped run starts from (its
+        ``engine.window`` spans are numbered from there)."""
+        return int(np.asarray(st.windows).reshape(-1)[0])
 
     def _fire_window_hook(self, st: EngineState) -> None:
         """Invoke the orchestrator's host observation point, if any.
@@ -987,7 +1032,8 @@ class Engine:
             self.window_hook(int(np.asarray(st.windows).reshape(-1)[0]), st)
 
     # ------------------------------------------------------------------- run
-    def _run_fn(self, axis: "str | ShardAxes | None", max_windows: int):
+    def _run_fn(self, axis: "str | ShardAxes | None", max_windows: int,
+                program: str = "run_local"):
         def cond(st: EngineState):
             return (~st.done) & (st.windows < max_windows)
 
@@ -995,6 +1041,9 @@ class Engine:
             return self._superstep(st, axis)
 
         def run(st: EngineState):
+            # a jitted function's body runs only while JAX traces it, so
+            # this counts traces of the driver program, never its runs
+            mon.count("engine.traces", key=program)
             return jax.lax.while_loop(cond, body, st)
 
         return run
@@ -1010,18 +1059,20 @@ class Engine:
         host-stepped (see :meth:`_run_hosted`) — the whole-run while_loop
         cannot carry the drain io_callbacks under a batched predicate, nor
         pause for a mid-run checkpoint save."""
-        if self._streaming or self._checkpointing:
-            return self._run_hosted(max_windows, state=state)
-        st = self.init_state() if state is None else state
-        key = ("run_local", max_windows, jit)
-        fn = self._jit_cache.get(key)
-        if fn is None:
-            fn = jax.vmap(self._run_fn(AXIS if self.spec.n_agents > 1 else None,
-                                       max_windows), axis_name=AXIS)
-            if jit:
-                fn = jax.jit(fn)
-            self._jit_cache[key] = fn
-        return fn(st)
+        with mon.span("engine.run", driver="local"):
+            if self._streaming or self._checkpointing:
+                return self._run_hosted(max_windows, state=state)
+            st = self.init_state() if state is None else state
+            key = ("run_local", max_windows, jit)
+            fn = self._jit_cache.get(key)
+            if fn is None:
+                axis = AXIS if self.spec.n_agents > 1 else None
+                fn = jax.vmap(self._run_fn(axis, max_windows, "run_local"),
+                              axis_name=AXIS)
+                if jit:
+                    fn = jax.jit(fn)
+                self._jit_cache[key] = fn
+            return fn(st)
 
     # ------------------------------------------------------- distributed run
     def _dist_axes(self, mesh: Mesh) -> ShardAxes:
@@ -1093,7 +1144,7 @@ class Engine:
         key = ("run_distributed", mesh, max_windows)
         fn = self._jit_cache.get(key)
         if fn is None:
-            inner = jax.vmap(self._run_fn(axes, max_windows),
+            inner = jax.vmap(self._run_fn(axes, max_windows, "dist_run"),
                              axis_name=axes.lane)
             fn = jax.jit(_shard_map(inner, mesh=mesh, in_specs=P(axes.shard),
                                     out_specs=P(axes.shard)))
@@ -1120,13 +1171,14 @@ class Engine:
         independently and the host merge is shard-major, matching
         ``merged_engine_trace``. Checkpoints save the unpadded state, so a
         resumed run may use a different mesh."""
-        if self._streaming or self._checkpointing:
-            return self._run_hosted(max_windows, state=state, mesh=mesh)
-        axes = self._dist_axes(mesh)
-        st = self._pad_state(self.init_state() if state is None else state,
-                             axes.size)
-        out = self._dist_run_fn(mesh, axes, max_windows)(st)
-        return self._slice_state(out)
+        with mon.span("engine.run", driver="distributed"):
+            if self._streaming or self._checkpointing:
+                return self._run_hosted(max_windows, state=state, mesh=mesh)
+            axes = self._dist_axes(mesh)
+            st = self._pad_state(self.init_state() if state is None else state,
+                                 axes.size)
+            out = self._dist_run_fn(mesh, axes, max_windows)(st)
+            return self._slice_state(out)
 
     # -------------------------------------------------------------- migration
     def _apply_placement(self, st: EngineState, new_lp_agent: jax.Array,
@@ -1159,8 +1211,12 @@ class Engine:
                               new_lp_agent: jax.Array) -> EngineState:
         """vmap driver for migration (new_lp_agent is fleet-global, (NLP,))."""
         axis = AXIS if self.spec.n_agents > 1 else None
-        fn = jax.vmap(lambda s: self._apply_placement(
-            s, new_lp_agent, axis), axis_name=AXIS)
+
+        def place(s):
+            mon.count("engine.traces", key="placement")
+            return self._apply_placement(s, new_lp_agent, axis)
+
+        fn = jax.vmap(place, axis_name=AXIS)
         return jax.jit(fn)(st)
 
     def apply_placement_distributed(self, st: EngineState,
@@ -1176,9 +1232,11 @@ class Engine:
         key = ("dist_placement", mesh)
         fn = self._jit_cache.get(key)
         if fn is None:
-            inner = jax.vmap(
-                lambda s, nla: self._apply_placement(s, nla, axes),
-                in_axes=(0, None), axis_name=axes.lane)
+            def place(s, nla):
+                mon.count("engine.traces", key="placement")
+                return self._apply_placement(s, nla, axes)
+
+            inner = jax.vmap(place, in_axes=(0, None), axis_name=axes.lane)
             fn = jax.jit(_shard_map(inner, mesh=mesh,
                                     in_specs=(P(axes.shard), P()),
                                     out_specs=P(axes.shard)))
@@ -1190,10 +1248,12 @@ class Engine:
         """One conservative window (vmap driver) — used by tests and benchmarks."""
         fn = self._jit_cache.get("step_local")
         if fn is None:
-            fn = jax.jit(jax.vmap(
-                lambda s: self._superstep(s, AXIS if self.spec.n_agents > 1
-                                          else None),
-                axis_name=AXIS))
+            def step(s):
+                mon.count("engine.traces", key="step_local")
+                return self._superstep(s, AXIS if self.spec.n_agents > 1
+                                       else None)
+
+            fn = jax.jit(jax.vmap(step, axis_name=AXIS))
             self._jit_cache["step_local"] = fn
         return fn(st)
 
@@ -1205,11 +1265,13 @@ class Engine:
         key = ("window_stream" if stream else "window", width)
         fn = self._jit_cache.get(key)
         if fn is None:
-            fn = jax.jit(jax.vmap(
-                lambda s: self._superstep(
+            def window(s):
+                mon.count("engine.traces", key="window")
+                return self._superstep(
                     s, AXIS if self.spec.n_agents > 1 else None,
-                    exec_cap=width, stream=stream),
-                axis_name=AXIS))
+                    exec_cap=width, stream=stream)
+
+            fn = jax.jit(jax.vmap(window, axis_name=AXIS))
             self._jit_cache[key] = fn
         return fn
 
@@ -1236,25 +1298,29 @@ class Engine:
         counters are exactly the save-time ``cur``, so a resumed trajectory
         concatenates byte-identically with the prefix).
         """
-        p = pol.normalize(self.spec.exec_policy if policy is None else policy)
-        self._begin_streams(p.ladder)
-        st = self.init_state() if state is None else state
-        rung = p.init_rung if rung is None else int(rung)
-        prev = np.asarray(st.counters)
-        rungs: list[int] = []
-        for _ in range(max_windows):
-            if bool(np.asarray(st.done).all()):
-                break
-            rungs.append(rung)
-            st = self._window_fn(p.ladder[rung])(st)
-            cur = np.asarray(st.counters)
-            stats = pol.window_stats(prev, cur, self.spec.pool_cap)
-            rung = pol.choose_rung(p, rung, stats)
-            prev = cur
-            self._checkpoint_window(st, rung=rung)
-            self._fire_window_hook(st)
-        self.adaptive_rungs = tuple(rungs)
-        return self._finalize_streams(st)
+        with mon.span("engine.run", driver="adaptive"):
+            p = pol.normalize(self.spec.exec_policy if policy is None
+                              else policy)
+            self._begin_streams(p.ladder)
+            st = self.init_state() if state is None else state
+            rung = p.init_rung if rung is None else int(rung)
+            prev = np.asarray(st.counters)
+            rungs: list[int] = []
+            w0 = self._first_window(st)
+            for i in range(max_windows):
+                if bool(np.asarray(st.done).all()):
+                    break
+                rungs.append(rung)
+                with mon.span("engine.window", step_num=w0 + i):
+                    st = self._window_fn(p.ladder[rung])(st)
+                    cur = np.asarray(st.counters)
+                    stats = pol.window_stats(prev, cur, self.spec.pool_cap)
+                    rung = pol.choose_rung(p, rung, stats)
+                    prev = cur
+                    self._checkpoint_window(st, rung=rung)
+                    self._fire_window_hook(st)
+            self.adaptive_rungs = tuple(rungs)
+            return self._finalize_streams(st)
 
     def _dist_window_fn(self, mesh: Mesh, width: int):
         """One jitted shard_map x vmap window program at a fixed exec width
@@ -1265,10 +1331,12 @@ class Engine:
         fn = self._jit_cache.get(key)
         if fn is None:
             axes = self._dist_axes(mesh)
-            inner = jax.vmap(
-                lambda s: self._superstep(s, axes, exec_cap=width,
-                                          stream=stream),
-                axis_name=axes.lane)
+            def window(s):
+                mon.count("engine.traces", key="dist_window")
+                return self._superstep(s, axes, exec_cap=width,
+                                       stream=stream)
+
+            inner = jax.vmap(window, axis_name=axes.lane)
             fn = jax.jit(_shard_map(inner, mesh=mesh, in_specs=P(axes.shard),
                                     out_specs=P(axes.shard)))
             self._jit_cache[key] = fn
@@ -1294,29 +1362,33 @@ class Engine:
         trajectory lands in ``self.adaptive_rungs``. ``state``/``rung``
         resume from a checkpoint — on any mesh, since checkpoints hold the
         unpadded state and this driver re-pads for the mesh it is given."""
-        p = pol.normalize(self.spec.exec_policy if policy is None else policy)
-        self._begin_streams(p.ladder)
-        axes = self._dist_axes(mesh)
-        A = self.spec.n_agents
-        st = self._pad_state(self.init_state() if state is None else state,
-                             axes.size)
-        rung = p.init_rung if rung is None else int(rung)
-        prev = np.asarray(st.counters)
-        rungs: list[int] = []
-        for _ in range(max_windows):
-            if bool(np.asarray(st.done)[:A].all()):
-                break
-            rungs.append(rung)
-            st = self._dist_window_fn(mesh, p.ladder[rung])(st)
-            cur = np.asarray(st.counters)
-            stats = pol.shard_window_stats(prev, cur, self.spec.pool_cap,
-                                           axes.n_shards)
-            rung = pol.choose_rung_lockstep(p, rung, stats)
-            prev = cur
-            self._checkpoint_window(st, rung=rung, padded=True)
-            self._fire_window_hook(st)
-        self.adaptive_rungs = tuple(rungs)
-        return self._finalize_streams(self._slice_state(st))
+        with mon.span("engine.run", driver="distributed_adaptive"):
+            p = pol.normalize(self.spec.exec_policy if policy is None
+                              else policy)
+            self._begin_streams(p.ladder)
+            axes = self._dist_axes(mesh)
+            A = self.spec.n_agents
+            st = self._pad_state(self.init_state() if state is None else state,
+                                 axes.size)
+            rung = p.init_rung if rung is None else int(rung)
+            prev = np.asarray(st.counters)
+            rungs: list[int] = []
+            w0 = self._first_window(st)
+            for i in range(max_windows):
+                if bool(np.asarray(st.done)[:A].all()):
+                    break
+                rungs.append(rung)
+                with mon.span("engine.window", step_num=w0 + i):
+                    st = self._dist_window_fn(mesh, p.ladder[rung])(st)
+                    cur = np.asarray(st.counters)
+                    stats = pol.shard_window_stats(
+                        prev, cur, self.spec.pool_cap, axes.n_shards)
+                    rung = pol.choose_rung_lockstep(p, rung, stats)
+                    prev = cur
+                    self._checkpoint_window(st, rung=rung, padded=True)
+                    self._fire_window_hook(st)
+            self.adaptive_rungs = tuple(rungs)
+            return self._finalize_streams(self._slice_state(st))
 
     # ------------------------------------------------------- ensemble driver
     def run_ensemble(self, seeds, max_windows: int = 10_000,
@@ -1337,38 +1409,39 @@ class Engine:
         "Millions of users" traffic in the paper's terms is exactly this:
         one launch sweeping seeds, not one hand-built spec per run.
         """
-        if self.trace_stream is not None:
-            raise ValueError(
-                "run_ensemble cannot stream traces (io_callback is "
-                "unsupported under the nested replica vmap); use a bounded "
-                "trace_cap for per-replica traces")
-        if self._checkpointing:
-            raise ValueError(
-                "run_ensemble is one fused program with no window "
-                "boundaries on the host; checkpoint cadence applies to the "
-                "single-run drivers")
-        seeds = jnp.asarray(seeds, jnp.int32).reshape(-1)
-        sfn = seed_fn or seed_rng_fields
-        skey = ("ensemble_seed", sfn)
-        seed_all = self._jit_cache.get(skey)
-        if seed_all is None:
-            seed_all = jax.jit(jax.vmap(sfn, in_axes=(None, 0)))
-            self._jit_cache[skey] = seed_all
-        key = ("run_ensemble", max_windows)
-        fn = self._jit_cache.get(key)
-        if fn is None:
-            inner = jax.vmap(self._run_fn(AXIS if self.spec.n_agents > 1
-                                          else None, max_windows),
-                             axis_name=AXIS)
-            fn = jax.jit(jax.vmap(inner))
-            self._jit_cache[key] = fn
-        out = fn(seed_all(self.init_state(), seeds))
-        ms = self.metrics_stream
-        if ms is not None:
-            ms.begin(self.spec.n_agents, self.registry)
-            ms.ensemble(np.asarray(seeds), np.asarray(out.counters),
-                        np.asarray(out.windows), np.asarray(out.t_now))
-        return out
+        with mon.span("engine.run", driver="ensemble"):
+            if self.trace_stream is not None:
+                raise ValueError(
+                    "run_ensemble cannot stream traces (io_callback is "
+                    "unsupported under the nested replica vmap); use a "
+                    "bounded trace_cap for per-replica traces")
+            if self._checkpointing:
+                raise ValueError(
+                    "run_ensemble is one fused program with no window "
+                    "boundaries on the host; checkpoint cadence applies to "
+                    "the single-run drivers")
+            seeds = jnp.asarray(seeds, jnp.int32).reshape(-1)
+            sfn = seed_fn or seed_rng_fields
+            skey = ("ensemble_seed", sfn)
+            seed_all = self._jit_cache.get(skey)
+            if seed_all is None:
+                seed_all = jax.jit(jax.vmap(sfn, in_axes=(None, 0)))
+                self._jit_cache[skey] = seed_all
+            key = ("run_ensemble", max_windows)
+            fn = self._jit_cache.get(key)
+            if fn is None:
+                axis = AXIS if self.spec.n_agents > 1 else None
+                inner = jax.vmap(self._run_fn(axis, max_windows, "ensemble"),
+                                 axis_name=AXIS)
+                fn = jax.jit(jax.vmap(inner))
+                self._jit_cache[key] = fn
+            out = fn(seed_all(self.init_state(), seeds))
+            ms = self.metrics_stream
+            if ms is not None:
+                ms.begin(self.spec.n_agents, self.registry)
+                ms.ensemble(np.asarray(seeds), np.asarray(out.counters),
+                            np.asarray(out.windows), np.asarray(out.t_now))
+            return out
 
 
 def seed_rng_fields(state: EngineState, seed) -> EngineState:

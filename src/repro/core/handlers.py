@@ -529,7 +529,8 @@ def apply_handler_batch(table, world, rows: ev.EventBatch,
     lanes_delta, lanes_counters, lanes_out = _dispatch_lanes(table, world, rows)
     masked = _mask_lanes(lanes_delta, active)
     n_rows = _count_rows(masked)
-    world = apply_delta(world, masked)
+    with mon.stage("merge"):
+        world = apply_delta(world, masked)
     return _finalize_batch(world, rows, active, lanes_counters, lanes_out,
                            n_rows)
 
@@ -555,8 +556,6 @@ def apply_handler_batch_dense(table, world, rows: ev.EventBatch,
         w2 = apply_delta(world, d)
         return {f: getattr(w2, f) for f in mutable}
 
-    lanes_mut = jax.vmap(lane_tables)(masked)
-
     def merge(lane_field, base):
         m = active.reshape((n_lanes,) + (1,) * base.ndim)
         changed = m & (lane_field != base[None])
@@ -564,7 +563,9 @@ def apply_handler_batch_dense(table, world, rows: ev.EventBatch,
         picked = jnp.take_along_axis(lane_field, pick[None], axis=0)[0]
         return jnp.where(jnp.any(changed, axis=0), picked, base)
 
-    world = world._replace(**{
-        f: merge(lanes_mut[f], getattr(world, f)) for f in mutable})
+    with mon.stage("merge"):
+        lanes_mut = jax.vmap(lane_tables)(masked)
+        world = world._replace(**{
+            f: merge(lanes_mut[f], getattr(world, f)) for f in mutable})
     return _finalize_batch(world, rows, active, lanes_counters, lanes_out,
                            n_rows)

@@ -16,10 +16,23 @@ host sink of the engine's device-side trace-ring drain
 docs/architecture.md, "Streaming trace"), and :class:`MetricsStream` turns the
 per-window counter vectors into periodic JSON-lines snapshots named by the
 registry's declared counter table.
+
+Host spans and counters (docs/architecture.md, "Observability") say where a
+run's host time goes: :func:`span` opens a ``jax.profiler.TraceAnnotation``
+named ``repro.<name>`` at each layer boundary (so the span sits in any
+profiler trace beside the device planes), and :func:`count` books host
+counts such as how many programs the engine traced. Both are recorded in
+memory only while a :class:`SpanLog` is attached; otherwise they book
+nothing. They are host-only: no span is opened inside traced code, and
+nothing here touches the in-graph counter vector.
 """
 from __future__ import annotations
 
+import contextlib
+import itertools
 import json
+import time
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -209,23 +222,6 @@ def performance_value(counters: jax.Array, n_owned_lps: jax.Array,
 
 
 # ------------------------------------------------------- host-streaming layer
-def counter_class(idx: int) -> str:
-    """The counter class of a builtin index: how a fleet snapshot should read
-    it (``gauge`` = per-window level, everything else accumulates) and which
-    equivalence contracts exempt it (``pool-diag`` / ``batch-diag``)."""
-    if idx in GAUGE_COUNTERS:
-        return "gauge"
-    if idx in DROP_COUNTERS:
-        return "drop"
-    if idx in POOL_DIAG_COUNTERS:
-        return "pool-diag"
-    if idx in BATCH_DIAG_COUNTERS:
-        return "batch-diag"
-    if idx in FLEET_COUNTERS:
-        return "fleet"
-    return "counter"
-
-
 def snapshot(counters, registry=None) -> dict:
     """Named view of a counter vector: ``{counter name: int total}``.
 
@@ -386,8 +382,8 @@ class MetricsStream:
         {"window": W, "gvt": T, "agents": A, "counters": {name: total}}
 
     Counter names and order come from the registry declaration (extension
-    counters included); ``counter_class``/``Registry.counter_docs`` give the
-    class and docstring of each name for richer consumers. A final snapshot
+    counters included); ``Registry.counter_docs`` gives the docstring of
+    each name for richer consumers. A final snapshot
     (``"final": true``) is emitted when the run finishes, whatever the
     cadence.
     """
@@ -534,3 +530,208 @@ class MetricsStream:
         """One replica's fleet-total counters by name (post-``ensemble``)."""
         return {name: int(self.replica_counters[r, i])
                 for name, i in self._names.items()}
+
+
+# ---------------------------------------------------- device stage scopes
+# The superstep's stages, each a ``jax.named_scope("superstep/<stage>")``
+# (engine._superstep and the calls it makes): the names land in every HLO
+# op's ``op_name`` metadata and so in the profiler's device op events. They
+# add metadata only; no op changes.
+STAGES = ("drain", "gvt", "select", "dispatch", "merge", "fallback", "trace",
+          "release", "route", "insert", "sync", "gauges")
+
+
+def stage(name: str):
+    """The named scope of superstep stage ``name`` (one of ``STAGES``)."""
+    return jax.named_scope("superstep/" + name)
+
+
+# ---------------------------------------------------- host spans and counters
+SPAN_PREFIX = "repro."
+
+# JAX's compile-time events (time.time() spans, the clock of SpanLog) and the
+# child span each becomes under the innermost open program span
+JAX_SPANS = {
+    "/jax/core/compile/jaxpr_trace_duration": "jax.trace_lower",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "jax.trace_lower",
+    "/jax/core/compile/backend_compile_duration": "jax.compile_load",
+}
+
+
+class Span(NamedTuple):
+    """One host span on the ``time.time_ns()`` clock. ``parent`` is the
+    index in ``SpanLog.spans`` of the span that encloses it; ``run_id`` the
+    id of the ``Orchestrator.run`` call it belongs to (None outside one)."""
+    name: str
+    start_ns: int
+    end_ns: int | None      # None while the span is open
+    parent: int | None
+    run_id: int | None
+    attrs: dict
+
+
+class Count(NamedTuple):
+    """One host count of ``name`` under ``key``."""
+    name: str
+    key: str
+
+
+_log: "SpanLog | None" = None   # the attached sink, if any
+_run_ids = itertools.count(1)
+
+
+def next_run_id() -> int:
+    """A fresh run id (process-wide, ascending from 1)."""
+    return next(_run_ids)
+
+
+@contextlib.contextmanager
+def span(name: str, run_id: int | None = None, **attrs):
+    """Host span ``repro.<name>`` around a block (or, as a decorator, a
+    call). Always a profiler ``TraceAnnotation`` (a ``StepTraceAnnotation``
+    when ``step_num`` is given), which costs next to nothing while no
+    profiler runs; recorded in memory only while a :class:`SpanLog` is
+    attached. ``run_id`` marks this span and every span under it as one
+    run's."""
+    tags = attrs if run_id is None else dict(attrs, run_id=run_id)
+    annotate = (jax.profiler.StepTraceAnnotation if "step_num" in attrs
+                else jax.profiler.TraceAnnotation)
+    with annotate(SPAN_PREFIX + name, **tags):
+        log = _log
+        if log is None:
+            yield
+            return
+        i = log._open(name, run_id, attrs)
+        try:
+            yield
+        finally:
+            log._close(i)
+
+
+def count(name: str, *, key: str = "") -> None:
+    """Book one of host counter ``name`` under ``key`` into the attached
+    :class:`SpanLog`; nothing without one."""
+    log = _log
+    if log is not None:
+        log.counts.append(Count(name, key))
+
+
+def _merge(intervals) -> list:
+    out: list = []
+    for a, b in sorted(intervals):
+        if out and a <= out[-1][1]:
+            out[-1][1] = max(out[-1][1], b)
+        else:
+            out.append([a, b])
+    return out
+
+
+def _measure(merged) -> int:
+    return sum(b - a for a, b in merged)
+
+
+def _overlap(xs, ys) -> int:
+    """Length of the intersection of two merged interval lists."""
+    total = i = j = 0
+    while i < len(xs) and j < len(ys):
+        lo, hi = max(xs[i][0], ys[j][0]), min(xs[i][1], ys[j][1])
+        total += max(hi - lo, 0)
+        if xs[i][1] < ys[j][1]:
+            i += 1
+        else:
+            j += 1
+    return total
+
+
+class SpanLog:
+    """In-memory sink of the program's host spans and counts.
+
+    Attach it as a context manager; everything is kept until it is read.
+    While attached it also listens to JAX's compile-time events: each
+    becomes a child span (``jax.trace_lower`` or ``jax.compile_load``) of the
+    innermost open program span, so a trace or a cache load is charged to
+    the layer that caused it. Only one SpanLog is attached at a time.
+    """
+
+    def __init__(self):
+        self.spans: list[Span] = []    # in the order they opened
+        self.counts: list[Count] = []
+        self._stack: list[int] = []    # indices of the open spans
+
+    def __enter__(self) -> "SpanLog":
+        global _log
+        if _log is not None:
+            raise RuntimeError("a SpanLog is already attached")
+        jax.monitoring.register_event_time_span_listener(self._on_jax)
+        _log = self
+        return self
+
+    def __exit__(self, *exc) -> None:
+        global _log
+        _log = None
+        jax.monitoring.unregister_event_time_span_listener(self._on_jax)
+
+    def _open(self, name: str, run_id, attrs) -> int:
+        parent = self._stack[-1] if self._stack else None
+        if run_id is None and parent is not None:
+            run_id = self.spans[parent].run_id
+        self.spans.append(Span(name, time.time_ns(), None, parent, run_id,
+                               attrs))
+        self._stack.append(len(self.spans) - 1)
+        return len(self.spans) - 1
+
+    def _close(self, i: int) -> None:
+        self._stack.remove(i)
+        self.spans[i] = self.spans[i]._replace(end_ns=time.time_ns())
+
+    def _on_jax(self, event, start, end, **kw) -> None:
+        name = JAX_SPANS.get(event)
+        if name is None:
+            return
+        parent = self._stack[-1] if self._stack else None
+        run_id = self.spans[parent].run_id if parent is not None else None
+        self.spans.append(Span(name, int(start * 1e9), int(end * 1e9), parent,
+                               run_id, kw))
+
+    # ------------------------------------------------------------- reading
+    def mark(self) -> tuple[int, int]:
+        """A point to read :meth:`since` and :meth:`union_s` from."""
+        return len(self.spans), len(self.counts)
+
+    def _closed(self, mark) -> dict[int, Span]:
+        return {i: s for i, s in enumerate(self.spans)
+                if i >= mark[0] and s.end_ns is not None}
+
+    def since(self, mark=(0, 0)) -> dict:
+        """What was booked since ``mark``: ``self_s``, seconds per span name
+        less the union of each span's children, and ``counts``, each
+        counter's total per key."""
+        spans = self._closed(mark)
+        own: dict[str, list] = {}
+        kids: dict[str, list] = {}
+        for s in spans.values():
+            own.setdefault(s.name, []).append((s.start_ns, s.end_ns))
+        for s in spans.values():
+            if s.parent in spans:
+                kids.setdefault(spans[s.parent].name, []).append(
+                    (s.start_ns, s.end_ns))
+        self_s = {}
+        for name, iv in own.items():
+            mine = _merge(iv)
+            self_s[name] = (_measure(mine)
+                            - _overlap(mine, _merge(kids.get(name, ())))) / 1e9
+        counts: dict[str, dict[str, int]] = {}
+        for c in self.counts[mark[1]:]:
+            per = counts.setdefault(c.name, {})
+            per[c.key] = per.get(c.key, 0) + 1
+        return dict(self_s=self_s, counts=counts)
+
+    def union_s(self, name: str, parent: str, mark=(0, 0)) -> float:
+        """Seconds covered by the spans ``name`` whose parent is a span
+        ``parent`` (JAX's trace and lowering of a driver program:
+        ``union_s("jax.trace_lower", "engine.run")``), since ``mark``."""
+        spans = self._closed(mark)
+        return _measure(_merge(
+            (s.start_ns, s.end_ns) for s in spans.values()
+            if s.name == name and s.parent is not None
+            and self.spans[s.parent].name == parent)) / 1e9

@@ -883,6 +883,7 @@ class ScenarioBuilderBase:
         self._seq += 1
 
     # ----------------------------------------------------------------- build
+    @_mon.span("builder.build")
     def build(self, *, n_agents: int = 1, n_ctx: int = 1, lookahead: int,
               t_end: int, pool_cap: int = 1024, emit_cap: int | None = None,
               route_cap: int | None = None, exec_cap: int | None = None,

@@ -47,6 +47,7 @@ import numpy as np
 from jax.sharding import Mesh
 
 from repro.checkpoint import SimCheckpointer
+from repro.core import monitoring as mon
 from repro.core import policy as pol_mod
 from repro.core.engine import Engine
 
@@ -253,74 +254,83 @@ class Orchestrator:
         Use a fresh ``checkpoint_dir`` per logical run: existing committed
         checkpoints in the directory are treated as *this* run's and
         auto-resumed (that is exactly the restart-after-SIGKILL contract).
+
+        The call is one ``orchestrator.run`` host span with a fresh run id,
+        which every program span under it shares.
         """
         pol = self.policy if policy is None else policy
-        world, own, init_events, spec = built
-        if pol.driver == "ensemble":
-            return self._run_ensemble(built, pol, seeds)
-        devices = list(jax.devices()) if devices is None else list(devices)
-        ck = None
-        if pol.checkpoint_dir is not None and pol.checkpoint_every > 0:
-            ck = SimCheckpointer(pol.checkpoint_dir,
-                                 every=pol.checkpoint_every,
-                                 keep=pol.checkpoint_keep,
-                                 kill_after=pol.kill_after)
+        with mon.span("orchestrator.run", run_id=mon.next_run_id(),
+                      driver=pol.driver):
+            world, own, init_events, spec = built
+            if pol.driver == "ensemble":
+                return self._run_ensemble(built, pol, seeds)
+            devices = (list(jax.devices()) if devices is None
+                       else list(devices))
+            ck = None
+            if pol.checkpoint_dir is not None and pol.checkpoint_every > 0:
+                ck = SimCheckpointer(pol.checkpoint_dir,
+                                     every=pol.checkpoint_every,
+                                     keep=pol.checkpoint_keep,
+                                     kill_after=pol.kill_after)
 
-        # The SIGKILL lane: a sidecar without the clean flag means the prior
-        # orchestrated process died mid-run — restore its books and count
-        # the death as the preemption it was.
-        prev = self._read_sidecar(pol)
-        saved_n_dev = None
-        if prev is not None and not prev.get("clean", False):
-            for name, value in (prev.get("counts") or {}).items():
-                if name in self.counts and value:
-                    self._book(name, int(value) - self.counts[name])
-            saved_n_dev = prev.get("n_devices")
-            self._book("PREEMPT")
-
-        attempt = 0
-        while True:
-            n_dev = len(devices)
-            if n_dev < pol.min_devices:
-                raise FleetError(
-                    f"degraded below the device floor: {n_dev} survivor(s) "
-                    f"< min_devices={pol.min_devices}")
-            driver = self._resolve_driver(pol, spec, n_dev)
-            engine = Engine(world, own, init_events, spec,
-                            trace_cap=self.trace_cap,
-                            trace_stream=self.trace_stream,
-                            metrics_stream=self.metrics_stream,
-                            drain_every=self.drain_every,
-                            checkpointer=ck,
-                            window_hook=self._hook(attempt))
-            state = rung = None
-            if ck is not None and ck.latest_step() is not None:
-                rec = engine.restore()
-                state, rung = rec.state, rec.rung
-                self._book("RESUME")
-                if saved_n_dev is not None and saved_n_dev != n_dev:
-                    self._book("RESHARD")
-            self._write_sidecar(pol, n_dev, clean=False)
-            try:
-                st = self._dispatch(engine, driver, pol, devices, state, rung)
-            except PreemptionError as e:
+            # The SIGKILL lane: a sidecar without the clean flag means the
+            # prior orchestrated process died mid-run — restore its books and
+            # count the death as the preemption it was.
+            prev = self._read_sidecar(pol)
+            saved_n_dev = None
+            if prev is not None and not prev.get("clean", False):
+                for name, value in (prev.get("counts") or {}).items():
+                    if name in self.counts and value:
+                        self._book(name, int(value) - self.counts[name])
+                saved_n_dev = prev.get("n_devices")
                 self._book("PREEMPT")
-                attempt += 1
-                if attempt > pol.max_retries:
+
+            attempt = 0
+            while True:
+                n_dev = len(devices)
+                if n_dev < pol.min_devices:
                     raise FleetError(
-                        f"retry cap exhausted: {attempt - 1} retries after "
-                        f"{self.counts['PREEMPT']} preemption(s)") from e
-                saved_n_dev = n_dev
-                if e.survivors < n_dev:
-                    devices = devices[:e.survivors]
-                if pol.backoff > 0:
-                    self._sleep(min(pol.backoff * 2 ** (attempt - 1),
-                                    pol.backoff_cap))
-                continue
-            self._write_sidecar(pol, n_dev, clean=True)
-            return OrchestratorResult(state=st, driver=driver, devices=n_dev,
-                                      attempts=attempt + 1,
-                                      counts=dict(self.counts))
+                        f"degraded below the device floor: {n_dev} "
+                        f"survivor(s) < min_devices={pol.min_devices}")
+                driver = self._resolve_driver(pol, spec, n_dev)
+                engine = Engine(world, own, init_events, spec,
+                                trace_cap=self.trace_cap,
+                                trace_stream=self.trace_stream,
+                                metrics_stream=self.metrics_stream,
+                                drain_every=self.drain_every,
+                                checkpointer=ck,
+                                window_hook=self._hook(attempt))
+                state = rung = None
+                if ck is not None and ck.latest_step() is not None:
+                    rec = engine.restore()
+                    state, rung = rec.state, rec.rung
+                    self._book("RESUME")
+                    if saved_n_dev is not None and saved_n_dev != n_dev:
+                        self._book("RESHARD")
+                self._write_sidecar(pol, n_dev, clean=False)
+                try:
+                    st = self._dispatch(engine, driver, pol, devices, state,
+                                        rung)
+                except PreemptionError as e:
+                    self._book("PREEMPT")
+                    attempt += 1
+                    if attempt > pol.max_retries:
+                        raise FleetError(
+                            f"retry cap exhausted: {attempt - 1} retries "
+                            f"after {self.counts['PREEMPT']} preemption(s)"
+                        ) from e
+                    saved_n_dev = n_dev
+                    if e.survivors < n_dev:
+                        devices = devices[:e.survivors]
+                    if pol.backoff > 0:
+                        self._sleep(min(pol.backoff * 2 ** (attempt - 1),
+                                        pol.backoff_cap))
+                    continue
+                self._write_sidecar(pol, n_dev, clean=True)
+                return OrchestratorResult(state=st, driver=driver,
+                                          devices=n_dev,
+                                          attempts=attempt + 1,
+                                          counts=dict(self.counts))
 
     def _run_ensemble(self, built, pol: FleetPolicy,
                       seeds) -> OrchestratorResult:

@@ -283,22 +283,6 @@ def test_snapshot_names_and_totals():
     }
 
 
-def test_counter_class():
-    assert mon.counter_class(mon.C_POOL_OCC) == "gauge"
-    assert mon.counter_class(mon.C_DROP_POOL) == "drop"
-    assert mon.counter_class(mon.C_RING_WRAP) == "pool-diag"
-    assert mon.counter_class(mon.C_BATCH_ROWS) == "batch-diag"
-    assert mon.counter_class(mon.C_EVENTS) == "counter"
-    assert mon.counter_class(mon.N_COUNTERS + 3) == "counter"
-    for idx in mon.FLEET_COUNTERS:
-        assert mon.counter_class(idx) == "fleet"
-    assert mon.FLEET_COUNTERS == (
-        mon.C_PREEMPT,
-        mon.C_RESUME,
-        mon.C_RESHARD,
-    )
-
-
 def test_metrics_stream_book_overlay():
     """Fleet counters are booked host-side (``MetricsStream.book``) and
     merged into every emitted record — the in-graph vector never carries
@@ -314,6 +298,7 @@ def test_metrics_stream_book_overlay():
         assert rec["counters"]["RESHARD"] == 0
     c = np.asarray(st.counters)
     assert int(c[:, list(mon.FLEET_COUNTERS)].sum()) == 0
+    assert mon.FLEET_COUNTERS == (mon.C_PREEMPT, mon.C_RESUME, mon.C_RESHARD)
 
 
 def test_counter_docs_follow_registry():

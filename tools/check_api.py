@@ -19,6 +19,19 @@ from __future__ import annotations
 import sys
 
 
+def _counter_class():
+    """``counter_class`` of the sibling ``gen_counter_docs.py``, the one
+    definition of a counter index's class."""
+    import importlib.util
+    import pathlib
+
+    path = pathlib.Path(__file__).with_name("gen_counter_docs.py")
+    spec = importlib.util.spec_from_file_location("gen_counter_docs", path)
+    m = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(m)
+    return m.counter_class
+
+
 def check() -> list[str]:
     import repro.core as core
     from repro.core import __all__ as public
@@ -131,11 +144,12 @@ def check() -> list[str]:
     missing = [n for n in fleet.__all__ if not hasattr(fleet, n)]
     if missing:
         errors.append(f"repro.fleet.__all__ names missing attributes: {missing}")
+    counter_class = _counter_class()
     for idx in mon.FLEET_COUNTERS:
-        if mon.counter_class(idx) != "fleet":
+        if counter_class(idx) != "fleet":
             errors.append(
                 f"counter {idx} in FLEET_COUNTERS but counter_class says "
-                f"{mon.counter_class(idx)!r} (must be 'fleet': booked "
+                f"{counter_class(idx)!r} (must be 'fleet': booked "
                 "host-side only)"
             )
 

@@ -3,7 +3,8 @@
 The counter declarations are the single source of truth: names, docstrings,
 and indices come from the registry (``monitoring.BUILTIN_COUNTERS`` seeds
 every ``Registry``; extensions add rows with ``Registry.counter(name, doc)``),
-and the class column comes from ``monitoring.counter_class``. This script
+and the class column from the class tuples of ``monitoring`` (``GAUGE_COUNTERS``,
+``DROP_COUNTERS``, ...). This script
 rewrites everything between the ``counter-table:begin`` / ``counter-table:end``
 markers in docs/architecture.md from a fresh registry — the same drift
 discipline ``check_api.py`` applies to the schema exports, but for the
@@ -42,6 +43,24 @@ _DRIFT_MSG = (
 )
 
 
+def counter_class(idx: int) -> str:
+    """The class of a counter index: how a fleet snapshot reads it
+    (``gauge`` = per-window level, everything else accumulates) and which
+    equivalence contracts exempt it (``pool-diag`` / ``batch-diag``)."""
+    from repro.core import monitoring as mon
+
+    for cls, members in (
+        ("gauge", mon.GAUGE_COUNTERS),
+        ("drop", mon.DROP_COUNTERS),
+        ("pool-diag", mon.POOL_DIAG_COUNTERS),
+        ("batch-diag", mon.BATCH_DIAG_COUNTERS),
+        ("fleet", mon.FLEET_COUNTERS),
+    ):
+        if idx in members:
+            return cls
+    return "counter"
+
+
 def counter_rows(registry) -> list[tuple[str, str, str]]:
     """(display name, class label, doc) rows in declared index order.
 
@@ -54,7 +73,7 @@ def counter_rows(registry) -> list[tuple[str, str, str]]:
     rows = []
     for name, idx in sorted(registry.counters.items(), key=lambda kv: kv[1]):
         shown = f"C_{name}" if idx < mon.N_COUNTERS else name
-        rows.append((shown, _CLASS_LABEL[mon.counter_class(idx)], docs.get(name, "")))
+        rows.append((shown, _CLASS_LABEL[counter_class(idx)], docs.get(name, "")))
     return rows
 
 
