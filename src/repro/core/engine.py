@@ -74,8 +74,11 @@ stays in lockstep on one jit-cached window program.
 """
 from __future__ import annotations
 
+import collections
 import functools
+import hashlib
 import os
+import threading
 from typing import Callable, NamedTuple
 
 import jax
@@ -102,6 +105,42 @@ AXIS = "agents"
 MULTICHIP_TPU_ENV = "REPRO_MULTICHIP_TPU"
 
 _shard_map = functools.partial(jax.shard_map, check_vma=False)
+
+# The process-wide cache of jitted driver programs (docs/architecture.md,
+# "Program cache"): engines of one structure share one program, so only the
+# first run of a structure traces, lowers and loads it. Bounded: the least
+# recently used program goes first. Small, because each engine also keeps
+# the programs it used, and XLA:CPU maps each loaded executable of a
+# whole-run program in 1,600-4,300 memory regions: a long-lived process (a
+# test worker) that also kept 8 or 32 such programs reached Linux's default
+# limit of 65,530 maps, and its next compile aborted.
+_PROGRAM_CACHE_SIZE = 2
+_programs: "collections.OrderedDict[tuple, Callable]" = (
+    collections.OrderedDict())
+_programs_lock = threading.Lock()
+
+
+def clear_programs() -> None:
+    """Empty the process-wide program cache: the next run of every
+    structure traces its driver programs anew."""
+    with _programs_lock:
+        _programs.clear()
+
+
+def _hook_key(fn):
+    """A hook as the structure key holds it: a ``functools.partial`` by its
+    func, args and keywords (the default ``group_fn`` is a new partial on
+    every Engine), anything else, and a partial of unhashable arguments, by
+    identity."""
+    if isinstance(fn, functools.partial):
+        key = (_hook_key(fn.func), fn.args, tuple(sorted(fn.keywords.items())))
+        try:
+            hash(key)
+        except TypeError:
+            return fn
+        return key
+    return fn
+
 
 class ShardAxes(NamedTuple):
     """The shard_map x vmap agent packing of ``run_distributed``.
@@ -354,10 +393,90 @@ class Engine:
                 n_res=self._n_res, n_tables=self.registry.n_tables)
             if self.slot_fn is None:
                 self.slot_fn = _ops.ring_slots
-        # jitted-driver cache: run_local/step_local build a fresh closure per
-        # call, which would otherwise defeat jax.jit's function-identity cache
-        # and recompile the whole superstep on every invocation
+        # the driver programs this engine has used (_program): its own
+        # streaming programs, and the shared ones it took from the
+        # process-wide cache, so its later runs look up nothing there
         self._jit_cache: dict = {}
+
+    # ------------------------------------------------------- program cache
+    _HOOKS = ("select_fn", "group_fn", "route_fn", "trace_fn", "fused_fn",
+              "slot_fn")
+    # the attributes a traced driver body reads: a structure-only copy of
+    # the engine holds these and nothing of a run
+    _STRUCTURE = ("spec", "registry", "own", "trace_cap", "table",
+                  "_n_res") + _HOOKS
+    # the methods a traced driver body calls through ``self``
+    _TRACED = ("_run_fn", "_superstep", "_execute_scan", "_execute_batched",
+               "_insert", "_route_and_insert", "_apply_placement")
+
+    @functools.cached_property
+    def _structure_key(self) -> tuple:
+        """What makes two engines' driver programs trace identically: every
+        value the traced body reads from ``self``.
+
+        - ``registry``, by identity (handler table, kind table, sync lists);
+        - ``spec``, a frozen dataclass (the handler table's lookahead and
+          work per MB come from it);
+        - the values of ``own``, as a digest of its leaves' bytes: they are
+          closed over as constants in ``sync_world``;
+        - ``_n_res``, the widest table's row count;
+        - ``trace_cap``, compared ``> 0`` in ``_execute_*`` (keyed by value:
+          the trace buffer's shape follows it anyway);
+        - the hooks ``select_fn``, ``group_fn``, ``route_fn``, ``trace_fn``,
+          ``fused_fn`` and ``slot_fn`` (:func:`_hook_key`);
+        - the traced methods as this engine's class holds them, so a
+          subclass or a patched method traces a program of its own.
+
+        The state's shapes need no entry: ``jax.jit`` keys on avals itself.
+        """
+        digest = hashlib.sha1()
+        for leaf in jax.tree.leaves(self.own):
+            a = np.asarray(leaf)
+            digest.update(f"{a.dtype}{a.shape}".encode())
+            digest.update(a.tobytes())
+        cls = type(self)
+        return (self.registry, self.spec, digest.hexdigest(), self._n_res,
+                self.trace_cap,
+                tuple(_hook_key(getattr(self, h)) for h in self._HOOKS),
+                tuple(getattr(cls, m) for m in self._TRACED))
+
+    def _program(self, key: tuple, make: Callable[["Engine"], Callable],
+                 shared: bool = True) -> Callable:
+        """The jitted driver program ``key`` of this engine's structure.
+
+        ``make(core)`` builds the program over ``core``. The engine keeps
+        every program it used. The first time it needs a shared one, it
+        looks it up in the process-wide cache, keyed by ``key`` and
+        :attr:`_structure_key` (booking ``engine.programs`` ``hit`` or
+        ``miss``); a miss builds it over a structure-only copy of this
+        engine (the ``_STRUCTURE`` attributes alone), so it holds no run's
+        world, initial events, streams or checkpointer. A program that
+        bakes this engine's stream callbacks in (``shared=False``) is built
+        over the engine itself and never shared: shared, it would deliver
+        one run's drains to another run's stream."""
+        fn = self._jit_cache.get(key)
+        if fn is None:
+            fn = self._jit_cache[key] = (self._shared_program(key, make)
+                                         if shared else make(self))
+        return fn
+
+    def _shared_program(self, key: tuple,
+                        make: Callable[["Engine"], Callable]) -> Callable:
+        full = (key, self._structure_key)
+        with _programs_lock:
+            fn = _programs.get(full)
+            if fn is not None:
+                mon.count("engine.programs", key="hit")
+                _programs.move_to_end(full)
+                return fn
+            mon.count("engine.programs", key="miss")
+            core = object.__new__(type(self))
+            for name in self._STRUCTURE:
+                setattr(core, name, getattr(self, name))
+            fn = _programs[full] = make(core)
+            while len(_programs) > _PROGRAM_CACHE_SIZE:
+                _programs.popitem(last=False)
+            return fn
 
     # ------------------------------------------------------------------ init
     @mon.span("engine.init_state")
@@ -1063,16 +1182,14 @@ class Engine:
             if self._streaming or self._checkpointing:
                 return self._run_hosted(max_windows, state=state)
             st = self.init_state() if state is None else state
-            key = ("run_local", max_windows, jit)
-            fn = self._jit_cache.get(key)
-            if fn is None:
-                axis = AXIS if self.spec.n_agents > 1 else None
-                fn = jax.vmap(self._run_fn(axis, max_windows, "run_local"),
+            axis = AXIS if self.spec.n_agents > 1 else None
+
+            def make(core):
+                fn = jax.vmap(core._run_fn(axis, max_windows, "run_local"),
                               axis_name=AXIS)
-                if jit:
-                    fn = jax.jit(fn)
-                self._jit_cache[key] = fn
-            return fn(st)
+                return jax.jit(fn) if jit else fn
+
+            return self._program(("run_local", max_windows, jit), make)(st)
 
     # ------------------------------------------------------- distributed run
     def _dist_axes(self, mesh: Mesh) -> ShardAxes:
@@ -1141,15 +1258,14 @@ class Engine:
         return jax.tree.map(lambda x: x[:A], st)
 
     def _dist_run_fn(self, mesh: Mesh, axes: ShardAxes, max_windows: int):
-        key = ("run_distributed", mesh, max_windows)
-        fn = self._jit_cache.get(key)
-        if fn is None:
-            inner = jax.vmap(self._run_fn(axes, max_windows, "dist_run"),
+        def make(core):
+            inner = jax.vmap(core._run_fn(axes, max_windows, "dist_run"),
                              axis_name=axes.lane)
-            fn = jax.jit(_shard_map(inner, mesh=mesh, in_specs=P(axes.shard),
-                                    out_specs=P(axes.shard)))
-            self._jit_cache[key] = fn
-        return fn
+            return jax.jit(_shard_map(inner, mesh=mesh,
+                                      in_specs=P(axes.shard),
+                                      out_specs=P(axes.shard)))
+
+        return self._program(("run_distributed", mesh, max_windows), make)
 
     def run_distributed(self, mesh: Mesh, max_windows: int = 10_000,
                         state: EngineState | None = None) -> EngineState:
@@ -1212,12 +1328,15 @@ class Engine:
         """vmap driver for migration (new_lp_agent is fleet-global, (NLP,))."""
         axis = AXIS if self.spec.n_agents > 1 else None
 
-        def place(s):
-            mon.count("engine.traces", key="placement")
-            return self._apply_placement(s, new_lp_agent, axis)
+        def make(core):
+            def place(s, nla):
+                mon.count("engine.traces", key="placement")
+                return core._apply_placement(s, nla, axis)
 
-        fn = jax.vmap(place, axis_name=AXIS)
-        return jax.jit(fn)(st)
+            return jax.jit(jax.vmap(place, in_axes=(0, None), axis_name=AXIS))
+
+        return self._program(("placement",), make)(
+            st, jnp.asarray(new_lp_agent, jnp.int32))
 
     def apply_placement_distributed(self, st: EngineState,
                                     new_lp_agent: jax.Array,
@@ -1229,51 +1348,52 @@ class Engine:
         unpadded migrated state — byte-identical to
         ``apply_placement_local`` on the same state."""
         axes = self._dist_axes(mesh)
-        key = ("dist_placement", mesh)
-        fn = self._jit_cache.get(key)
-        if fn is None:
+
+        def make(core):
             def place(s, nla):
                 mon.count("engine.traces", key="placement")
-                return self._apply_placement(s, nla, axes)
+                return core._apply_placement(s, nla, axes)
 
             inner = jax.vmap(place, in_axes=(0, None), axis_name=axes.lane)
-            fn = jax.jit(_shard_map(inner, mesh=mesh,
-                                    in_specs=(P(axes.shard), P()),
-                                    out_specs=P(axes.shard)))
-            self._jit_cache[key] = fn
+            return jax.jit(_shard_map(inner, mesh=mesh,
+                                      in_specs=(P(axes.shard), P()),
+                                      out_specs=P(axes.shard)))
+
+        fn = self._program(("dist_placement", mesh), make)
         return self._slice_state(fn(self._pad_state(st, axes.size),
-                                    new_lp_agent))
+                                    jnp.asarray(new_lp_agent, jnp.int32)))
 
     def step_local(self, st: EngineState) -> EngineState:
         """One conservative window (vmap driver) — used by tests and benchmarks."""
-        fn = self._jit_cache.get("step_local")
-        if fn is None:
+        axis = AXIS if self.spec.n_agents > 1 else None
+
+        def make(core):
             def step(s):
                 mon.count("engine.traces", key="step_local")
-                return self._superstep(s, AXIS if self.spec.n_agents > 1
-                                       else None)
+                return core._superstep(s, axis)
 
-            fn = jax.jit(jax.vmap(step, axis_name=AXIS))
-            self._jit_cache["step_local"] = fn
-        return fn(st)
+            return jax.jit(jax.vmap(step, axis_name=AXIS))
+
+        return self._program(("step_local",), make)(st)
 
     # ------------------------------------------------------ adaptive driver
     def _window_fn(self, width: int):
         """One jitted window program at a fixed exec width (cached per rung,
-        so the adaptive ladder recompiles nothing after first use)."""
+        so the adaptive ladder recompiles nothing after first use). With a
+        stream attached the program carries this engine's callbacks and
+        stays on the engine."""
         stream = self._streaming
-        key = ("window_stream" if stream else "window", width)
-        fn = self._jit_cache.get(key)
-        if fn is None:
+        axis = AXIS if self.spec.n_agents > 1 else None
+
+        def make(core):
             def window(s):
                 mon.count("engine.traces", key="window")
-                return self._superstep(
-                    s, AXIS if self.spec.n_agents > 1 else None,
-                    exec_cap=width, stream=stream)
+                return core._superstep(s, axis, exec_cap=width, stream=stream)
 
-            fn = jax.jit(jax.vmap(window, axis_name=AXIS))
-            self._jit_cache[key] = fn
-        return fn
+            return jax.jit(jax.vmap(window, axis_name=AXIS))
+
+        return self._program(("window_stream" if stream else "window", width),
+                             make, shared=not stream)
 
     def run_adaptive(self, max_windows: int = 10_000,
                      policy: "pol.ExecPolicy | int | None" = None,
@@ -1325,22 +1445,25 @@ class Engine:
     def _dist_window_fn(self, mesh: Mesh, width: int):
         """One jitted shard_map x vmap window program at a fixed exec width
         (cached per (mesh, rung) — lockstep adaptation recompiles nothing
-        after each rung's first use)."""
+        after each rung's first use). With a stream attached the program
+        carries this engine's callbacks and stays on the engine."""
         stream = self._streaming
-        key = ("dist_window_stream" if stream else "dist_window", mesh, width)
-        fn = self._jit_cache.get(key)
-        if fn is None:
-            axes = self._dist_axes(mesh)
+        axes = self._dist_axes(mesh)
+
+        def make(core):
             def window(s):
                 mon.count("engine.traces", key="dist_window")
-                return self._superstep(s, axes, exec_cap=width,
+                return core._superstep(s, axes, exec_cap=width,
                                        stream=stream)
 
             inner = jax.vmap(window, axis_name=axes.lane)
-            fn = jax.jit(_shard_map(inner, mesh=mesh, in_specs=P(axes.shard),
-                                    out_specs=P(axes.shard)))
-            self._jit_cache[key] = fn
-        return fn
+            return jax.jit(_shard_map(inner, mesh=mesh,
+                                      in_specs=P(axes.shard),
+                                      out_specs=P(axes.shard)))
+
+        return self._program(
+            ("dist_window_stream" if stream else "dist_window", mesh, width),
+            make, shared=not stream)
 
     def run_distributed_adaptive(self, mesh: Mesh, max_windows: int = 10_000,
                                  policy: "pol.ExecPolicy | int | None" = None,
@@ -1422,19 +1545,17 @@ class Engine:
                     "the single-run drivers")
             seeds = jnp.asarray(seeds, jnp.int32).reshape(-1)
             sfn = seed_fn or seed_rng_fields
-            skey = ("ensemble_seed", sfn)
-            seed_all = self._jit_cache.get(skey)
-            if seed_all is None:
-                seed_all = jax.jit(jax.vmap(sfn, in_axes=(None, 0)))
-                self._jit_cache[skey] = seed_all
-            key = ("run_ensemble", max_windows)
-            fn = self._jit_cache.get(key)
-            if fn is None:
-                axis = AXIS if self.spec.n_agents > 1 else None
-                inner = jax.vmap(self._run_fn(axis, max_windows, "ensemble"),
+            seed_all = self._program(
+                ("ensemble_seed", sfn),
+                lambda core: jax.jit(jax.vmap(sfn, in_axes=(None, 0))))
+            axis = AXIS if self.spec.n_agents > 1 else None
+
+            def make(core):
+                inner = jax.vmap(core._run_fn(axis, max_windows, "ensemble"),
                                  axis_name=AXIS)
-                fn = jax.jit(jax.vmap(inner))
-                self._jit_cache[key] = fn
+                return jax.jit(jax.vmap(inner))
+
+            fn = self._program(("run_ensemble", max_windows), make)
             out = fn(seed_all(self.init_state(), seeds))
             ms = self.metrics_stream
             if ms is not None:

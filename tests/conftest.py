@@ -5,6 +5,23 @@ import pytest
 from repro.core import ScenarioBuilder
 
 
+def pytest_configure(config):
+    # pytest-xdist's loadfile mode sends whole files to workers, by default
+    # in descending order of their test count, which sends the few-test,
+    # long property files last and makes them the tail of a parallel run;
+    # keep the collection order below instead
+    if hasattr(config.option, "loadscopereorder"):
+        config.option.loadscopereorder = False
+
+
+def pytest_collection_modifyitems(items):
+    """Files that hold hypothesis property tests run longest: collect them
+    first (file order otherwise kept), so a parallel run starts them first."""
+    prop = {it.path for it in items
+            if getattr(getattr(it, "obj", None), "is_hypothesis_test", False)}
+    items.sort(key=lambda it: it.path not in prop)
+
+
 def t0t1_builder(*, wan_bw=2.0, n_flows=12, interval=25, flow_mb=40.0,
                  lookahead=2):
     """The paper's T0/T1 replication study, small: production at T0 generates

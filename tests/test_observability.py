@@ -15,6 +15,7 @@ import pytest
 from conftest import t0t1_builder
 from repro.core import Engine, MetricsStream, TraceStream
 from repro.core import monitoring as mon
+from repro.core.engine import clear_programs
 
 
 def test_spans_nest_with_parent_self_time_and_run_id():
@@ -122,12 +123,15 @@ def _engine(**kw):
 
 def test_engine_counts_one_trace_per_program():
     # the sequential fold compiles in half the batched dispatch's time, and
-    # the count does not depend on the execution path
+    # the count does not depend on the execution path; an emptied program
+    # cache makes the first run trace whatever ran before in this process
     w, o, e, s = _engine(n_agents=1, batched_dispatch=False)
+    clear_programs()
     with mon.SpanLog() as log:
         eng = Engine(w, o, e, s, trace_cap=256)
         eng.run_local(max_windows=8)
-        assert log.since()["counts"] == {"engine.traces": {"run_local": 1}}
+        assert log.since()["counts"] == {"engine.traces": {"run_local": 1},
+                                         "engine.programs": {"miss": 1}}
         first = log.mark()
         eng.run_local(max_windows=8)
         assert log.since(first)["counts"] == {}      # cached: no new trace
@@ -141,7 +145,9 @@ def test_engine_counts_one_trace_per_program():
 
 def test_every_stage_scope_reaches_the_lowered_program():
     """Two agents and both streams: one window program holds every stage.
-    A new Engine traces its program anew: one more count."""
+    A streaming program carries its engine's callbacks, so it is never
+    shared: a new Engine traces its program anew, whatever ran before in
+    this process, and books no ``engine.programs``."""
     w, o, e, s = _engine(n_agents=2)
     with mon.SpanLog() as log:
         eng = Engine(w, o, e, s, trace_cap=64, trace_stream=TraceStream(),

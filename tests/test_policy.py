@@ -21,6 +21,7 @@ from repro.core import (
 )
 from repro.core import monitoring as mon
 from repro.core import policy as pol
+from repro.core.engine import _programs, clear_programs
 from conftest import t0t1_builder
 
 LADDER = pol.ExecPolicy(ladder=(64, 256, 1024))
@@ -206,9 +207,18 @@ def test_rung_programs_are_jit_cached():
         width=64, exec_policy=pol.ExecPolicy(ladder=(32, 64))
     )
     eng = Engine(world, own, init_ev, spec)
-    eng.run_adaptive()
-    cached = {k for k in eng._jit_cache if isinstance(k, tuple) and k[0] == "window"}
-    assert cached <= {("window", 32), ("window", 64)} and cached
-    before = {k: id(v) for k, v in eng._jit_cache.items()}
-    eng.run_adaptive()  # second run: no new entries, no recompiles
-    assert {k: id(v) for k, v in eng._jit_cache.items()} == before
+    clear_programs()
+    with mon.SpanLog() as log:
+        eng.run_adaptive()
+        first = log.since()["counts"]
+        cached = {k[0] for k in _programs}
+        assert cached <= {("window", 32), ("window", 64)} and cached
+        # one program per rung used, traced once
+        assert first["engine.traces"] == {"window": len(cached)}
+        assert first["engine.programs"] == {"miss": len(cached)}
+        before = {k: id(v) for k, v in _programs.items()}
+        second = log.mark()
+        eng.run_adaptive()  # second run: no new entries, no recompiles
+        assert {k: id(v) for k, v in _programs.items()} == before
+        # the engine kept its rung programs: no lookup, no trace
+        assert log.since(second)["counts"] == {}
